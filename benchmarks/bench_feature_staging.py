@@ -78,7 +78,9 @@ def _time_fetch(pipe, frontier, staged_rows, repeats=30):
 
     cache_ax = None if cache is None else 0
     staged_ax = None if staged_rows is None else 0
-    fetch_j = jax.jit(jax.vmap(worker, in_axes=(0, 0, cache_ax, staged_ax),
+    shard_ax = pipe.shards.axes(0, None)
+    fetch_j = jax.jit(jax.vmap(worker,
+                               in_axes=(shard_ax, 0, cache_ax, staged_ax),
                                axis_name=dist.AXIS))
     out = fetch_j(pipe.shards, frontier, cache, staged_rows)
     jax.block_until_ready(out)

@@ -505,7 +505,7 @@ def make_cached_worker_step(*, graph_replicated, offsets, num_parts,
 def run_stacked_cached(step, params, shards, seeds, salt,
                        cache: FeatureCache):
     """vmap simulation with per-worker cache slices (cf. dist.run_stacked)."""
-    vstep = jax.vmap(step, in_axes=(None, 0, 0, None, 0),
+    vstep = jax.vmap(step, in_axes=(None, shards.axes(0, None), 0, None, 0),
                      axis_name=dist.AXIS)
     loss, grads, hit_rate = vstep(params, shards, seeds, salt, cache)
     return loss[0], jax.tree.map(lambda g: g[0], grads), jnp.mean(hit_rate)

@@ -33,7 +33,7 @@ from __future__ import annotations
 import dataclasses
 import warnings
 from functools import partial
-from typing import Callable, Sequence
+from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -348,20 +348,39 @@ class WorkerShard:
     """Per-worker slice of the partitioned data (leading P axis when stacked).
 
     Vanilla workers use local_indptr/local_indices; hybrid workers ignore
-    them (topology is a replicated closure constant instead).
+    them.  ``replicated`` is the topology every worker holds whole — the
+    placement plan's ``replicated_state()`` (the full CSC for hybrid, the
+    hot subgraph for hybrid_partial, ``None`` for vanilla).  It has no
+    worker axis: executors broadcast it (vmap) or replicate it over the
+    mesh (shard_map), and it enters the jitted step as an argument, never
+    as a constant baked into the program.
     """
     features: jnp.ndarray       # (n_max, D)
     labels: jnp.ndarray         # (n_max,)
     local_indptr: jnp.ndarray   # (n_max + 1,)
     local_indices: jnp.ndarray  # (nnz_max,)
+    replicated: Any = None
 
     def tree_flatten(self):
         return (self.features, self.labels, self.local_indptr,
-                self.local_indices), None
+                self.local_indices, self.replicated), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(*children)
+
+    def axes(self, worker, whole):
+        """A prefix tree giving ``worker`` for the per-worker fields and
+        ``whole`` for ``replicated`` — the in_axes / PartitionSpec /
+        sharding tree executors bind a stacked shard with."""
+        return WorkerShard(worker, worker, worker, worker, whole)
+
+    def worker(self, i):
+        """Worker ``i``'s slice of a stacked shard (``replicated`` is
+        shared, not sliced)."""
+        return WorkerShard(self.features[i], self.labels[i],
+                           self.local_indptr[i], self.local_indices[i],
+                           self.replicated)
 
 
 # --------------------------------------------------------------------------
@@ -570,7 +589,8 @@ def make_worker_step(*, graph_replicated: CSCGraph | None,
 
 def run_stacked(step, params, shards: WorkerShard, seeds, salt):
     """Single-device simulation: vmap over the stacked worker axis."""
-    vstep = jax.vmap(step, in_axes=(None, 0, 0, None), axis_name=AXIS)
+    vstep = jax.vmap(step, in_axes=(None, shards.axes(0, None), 0, None),
+                     axis_name=AXIS)
     loss, grads = vstep(params, shards, seeds, salt)
     # pmean makes every worker's copy identical; take worker 0's
     return loss[0], jax.tree.map(lambda g: g[0], grads)
@@ -580,8 +600,6 @@ def make_shard_map_step(step, mesh, params_spec, shard_spec, seeds_spec):
     """Production path: the same per-worker program under shard_map."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     def wrapper(params, shards, seeds, salt):
         squeeze = lambda a: a[0]
         shards1 = jax.tree.map(squeeze, shards)
@@ -589,8 +607,8 @@ def make_shard_map_step(step, mesh, params_spec, shard_spec, seeds_spec):
         loss, grads = step(params, shards1, seeds1, salt)
         return loss, grads
 
-    return shard_map(
+    return jax.shard_map(
         wrapper, mesh=mesh,
         in_specs=(params_spec, shard_spec, seeds_spec, P()),
         out_specs=(P(), params_spec),
-        check=False)
+        check_vma=False)

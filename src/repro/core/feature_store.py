@@ -39,6 +39,7 @@ from __future__ import annotations
 
 from typing import Callable
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import dist
@@ -138,9 +139,9 @@ class PinnedHotStore(FeatureStore):
 
     ``gather`` selects the hit-row path: ``"kernel"`` always uses the
     Pallas kernel, ``"jnp"`` the oracle, ``"auto"`` (default) the kernel
-    only when kernels run compiled (interpret-mode Pallas is correct but
-    slow, so CPU CI hot paths stay on the oracle; the kernel itself is
-    covered by tier-1 interpret tests).
+    on an accelerator and the oracle on the CPU backend (interpret-mode
+    Pallas is correct but slow; the kernel itself is covered by tier-1
+    interpret tests).
     """
 
     name = "pinned_hot"
@@ -158,10 +159,9 @@ class PinnedHotStore(FeatureStore):
             return gather_rows_reference(rows, hit_pos)
         if self.gather == "kernel":
             return gather_rows(rows, hit_pos)
-        from repro.kernels.ops import INTERPRET
-        if INTERPRET:
+        if jax.default_backend() == "cpu":
             return gather_rows_reference(rows, hit_pos)
-        return gather_rows(rows, hit_pos, interpret=False)
+        return gather_rows(rows, hit_pos)
 
     def fetch(self, src_nodes, shard, cache, *, offsets, num_parts,
               counter=None, staged_rows=None):
@@ -229,8 +229,7 @@ class StagedStore(FeatureStore):
         bit-equivalence tests run both)."""
         if self.combine != "auto":
             return self.combine == "device"
-        from repro.kernels.ops import INTERPRET
-        return not INTERPRET
+        return jax.default_backend() != "cpu"
 
     def fetch(self, src_nodes, shard, cache, *, offsets, num_parts,
               counter=None, staged_rows=None):

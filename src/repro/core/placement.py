@@ -67,9 +67,11 @@ from repro.core.sampler import level_salt, sample_neighbors
 class PlacementPlan:
     """Host-side product of ``scheme.build(layout)``.
 
-    Holds the partition boundaries plus whatever replicated topology the
-    scheme's sampling program closes over, and — when built from a layout —
-    the stacked per-worker local topology for ``WorkerShard`` construction.
+    Holds the partition boundaries plus whatever topology the scheme
+    replicates on every worker (``replicated_state``, which pipelines hand
+    to the step through ``WorkerShard.replicated``), and — when built from
+    a layout — the stacked per-worker local topology for ``WorkerShard``
+    construction.
 
     Attributes
     ----------
@@ -131,6 +133,20 @@ class PlacementPlan:
         """Fully-replicated topology, when the scheme has one (hybrid)."""
         return None
 
+    def replicated_state(self):
+        """The topology every worker holds whole, as the pytree the traced
+        sampling program reads (``WorkerShard.replicated``); ``None`` when
+        the scheme replicates nothing."""
+        return None
+
+    def replicated_of(self, shard):
+        """``shard.replicated`` — how pipelines hand the replicated state
+        to the step, as a jit argument — else this plan's own copy (plans
+        built without a pipeline, e.g. ``plan_from_legacy``)."""
+        if shard.replicated is not None:
+            return shard.replicated
+        return self.replicated_state()
+
 
 def _remote_edge_mass(layout, src_mask: np.ndarray | None = None) -> float:
     """Fraction of the layout's edges whose source is owned by a
@@ -162,11 +178,14 @@ def _placeholder_topology(num_parts: int):
 
 @dataclasses.dataclass(frozen=True)
 class HybridPlacementPlan(PlacementPlan):
-    """Hybrid plan: the replicated topology is a closure constant."""
+    """Hybrid plan: every worker holds the whole topology."""
     graph: CSCGraph | None = None
 
     @property
     def replicated_graph(self) -> CSCGraph | None:
+        return self.graph
+
+    def replicated_state(self):
         return self.graph
 
 
@@ -208,6 +227,9 @@ class PartialPlacementPlan(PlacementPlan):
     cold_remote_source_fraction: float = 1.0
     replicated_edges: int = 0
     replicated_edge_fraction: float = 0.0
+
+    def replicated_state(self):
+        return (self.hot_graph, self.hot_mask)
 
     @property
     def complete(self) -> bool:
@@ -320,9 +342,10 @@ class HybridScheme(PlacementScheme):
 
     def sample(self, plan, shard, seeds, fanouts, salt, *, level_fn=None,
                fused: bool = False, counter=None):
-        if plan.graph is None:
+        graph = plan.replicated_of(shard)
+        if graph is None:
             raise ValueError("hybrid scheme needs the replicated topology")
-        mfgs = dist.hybrid_sample(plan.graph, seeds, fanouts, salt,
+        mfgs = dist.hybrid_sample(graph, seeds, fanouts, salt,
                                   level_fn=level_fn)
         return mfgs, jnp.zeros((), jnp.float32)
 
@@ -422,6 +445,7 @@ class HybridPartialScheme(PlacementScheme):
         n_local = offsets[me + 1] - my_offset
         hot_any = plan.hot_count > 0        # static: specializes the trace
         complete = plan.complete
+        hot_graph, hot_mask = plan.replicated_of(shard)
 
         util = jnp.zeros((), jnp.float32)
         mfgs: list[MFG] = []
@@ -435,11 +459,11 @@ class HybridPartialScheme(PlacementScheme):
                     n_local, frontier, fanout, level_salt(salt, depth))
             else:
                 if hot_any:
-                    is_hot = (plan.hot_mask[jnp.clip(frontier, 0)]
+                    is_hot = (hot_mask[jnp.clip(frontier, 0)]
                               & (frontier >= 0))
                     hot_frontier = jnp.where(is_hot, frontier, -1)
                     hot_samples, _ = sample_neighbors(
-                        plan.hot_graph, hot_frontier, fanout,
+                        hot_graph, hot_frontier, fanout,
                         level_salt(salt, depth))
                 if complete:
                     samples = hot_samples

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 TILE_I = 128
 TILE_T = 128
 
@@ -49,7 +51,7 @@ def _gather_kernel(ids_ref, table_ref, out_ref, *, num_table_tiles):
                                              "interpret"))
 def feature_gather(ids: jnp.ndarray, table: jnp.ndarray, *,
                    tile_i: int = TILE_I, tile_t: int = TILE_T,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: bool | None = None) -> jnp.ndarray:
     """ids (N,) int32 [-1 -> zero row]; table (M, D) -> (N, D)."""
     N = ids.shape[0]
     M, D = table.shape
@@ -70,6 +72,6 @@ def feature_gather(ids: jnp.ndarray, table: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((tile_i, D), lambda i, t: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N_pad, D), table.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(ids_p, tbl_p)
     return out[:N]

@@ -1,23 +1,26 @@
-"""Double-buffered Pallas row gather — the feature-store device hot path.
+"""Pallas row gather — the feature-store device hot path.
 
 ``fetch_features`` moves (N, D) feature rows through two ``all_to_all``
 rounds; the pinned/staged feature stores (``repro.core.feature_store``)
 replace the hot part of that stream with a plain device-memory gather
 from a pinned table.  This kernel is that gather, written the way the
-``fused_sample`` kernel streams neighbor windows: the table stays in HBM
-(``pl.ANY``), each requested row rides an explicit async DMA into a
-2-slot VMEM scratch ring, and the DMA of row *j+1* is started *before*
-the copy of row *j* is waited on — so on TPU the HBM fetch latency hides
-behind the previous row's VMEM write (guide: "Patterns: Double
-Buffering").
+``fused_sample`` kernel streams neighbor rows: the table stays in HBM
+(``pl.ANY``), the block's ids arrive in SMEM, every requested row of the
+block rides its own async DMA into a VMEM scratch, and all of them are
+in flight before the first is waited on.  The Pallas pipeline
+double-buffers the output blocks, so block *i*'s write-back overlaps
+block *i+1*'s row DMAs.
+
+TPU DMAs slice HBM in whole 128-lane tiles, so a table whose width is
+not a multiple of 128 is padded to one in the wrapper; the kernel copies
+the first ``D`` lanes of each row out.
 
 Semantics match the ``jnp.take`` oracle bit for bit, including the
 feature path's padding convention: ids that are ``-1`` (padded frontier
 slots) or otherwise out of ``[0, K)`` produce exact ``+0.0`` rows.
 
-Validated with ``interpret=True`` on CPU (tier-1:
-``tests/test_kernels.py``); the same ``pallas_call`` compiles natively
-on TPU.
+Tests run the kernel in interpret mode on CPU (``tests/test_kernels.py``);
+``tests/test_tpu_compile.py`` compiles it for a v5e chip at real shapes.
 """
 from __future__ import annotations
 
@@ -28,9 +31,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# rows gathered per grid step; the double-buffer ring pipelines row DMAs
-# within a block while the pallas pipeline overlaps the out-block writes
-BLOCK_ROWS = 8
+from repro.kernels import resolve_interpret
+
+# rows gathered per grid step: the ids arrive as a 1-D int32 SMEM block,
+# which must match the 1024-element tile XLA gives such arrays on TPU
+BLOCK_ROWS = 1024
+LANES = 128
 
 
 def gather_rows_reference(table: jnp.ndarray,
@@ -46,53 +52,47 @@ def gather_rows_reference(table: jnp.ndarray,
     return jnp.where(ok[:, None], rows, jnp.zeros_like(rows))
 
 
-def _gather_kernel(ids_ref, table_ref, out_ref, scratch, sems, *,
-                   block: int, num_ids: int, table_rows: int):
-    blk = pl.program_id(0)
-    base = blk * block
+def _gather_kernel(ids_ref, table_hbm, out_ref, rows, sem, *, block: int,
+                   table_rows: int, width: int):
+    def row_dma(j, idx):
+        return pltpu.make_async_copy(table_hbm.at[pl.ds(idx, 1)],
+                                     rows.at[pl.ds(j, 1)], sem.at[0])
 
-    def idx_ok(j):
-        raw = pl.load(ids_ref, (pl.dslice(base + j, 1),))[0]
-        ok = (raw >= 0) & (raw < table_rows)
-        return jnp.where(ok, raw, 0), ok
+    def row_ok(j):
+        raw = ids_ref[j]
+        return (raw >= 0) & (raw < table_rows), raw
 
-    def row_dma(j, slot):
-        # invalid ids clamp the DMA to row 0 (always resident); the copy
-        # is discarded by the `ok` select below, never read as data
-        idx, _ = idx_ok(j)
-        return pltpu.make_async_copy(table_ref.at[pl.ds(idx, 1)],
-                                     scratch.at[slot], sems.at[slot])
+    def start(j, carry):
+        ok, raw = row_ok(j)
 
-    # warm-up: row 0's DMA in flight before the loop body runs
-    row_dma(0, 0).start()
+        @pl.when(ok)
+        def _fetch():
+            row_dma(j, raw).start()
 
-    def body(j, carry):
-        slot = jax.lax.rem(j, 2)
-        nxt = jax.lax.rem(j + 1, 2)
-
-        # start row j+1's HBM->VMEM copy *before* waiting on row j's —
-        # the overlap that makes the ring a double buffer
-        @pl.when(j + 1 < block)
-        def _():
-            row_dma(j + 1, nxt).start()
-
-        row_dma(j, slot).wait()
-        _, ok = idx_ok(j)
-        row = scratch[slot, 0, :]
-        row = jnp.where(ok, row, jnp.zeros_like(row))
-        pl.store(out_ref, (pl.dslice(j, 1), slice(None)),
-                 row.reshape(1, -1))
+        @pl.when(jnp.logical_not(ok))
+        def _zero():
+            rows[pl.ds(j, 1), :] = jnp.zeros((1, rows.shape[1]), rows.dtype)
         return carry
 
-    jax.lax.fori_loop(0, block, body, 0)
+    def wait(j, carry):
+        ok, raw = row_ok(j)
+
+        @pl.when(ok)
+        def _wait():
+            row_dma(j, raw).wait()
+        return carry
+
+    # every row DMA of the block is in flight before the first wait
+    jax.lax.fori_loop(0, block, start, 0)
+    jax.lax.fori_loop(0, block, wait, 0)
+    out_ref[...] = rows[:, :width]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def gather_rows(table: jnp.ndarray, ids: jnp.ndarray, *,
                 block: int = BLOCK_ROWS,
-                interpret: bool = True) -> jnp.ndarray:
-    """Gather ``table[ids]`` with double-buffered row DMAs.
+                interpret: bool | None = None) -> jnp.ndarray:
+    """Gather ``table[ids]`` with one async row DMA per id.
 
     Parameters
     ----------
@@ -101,10 +101,11 @@ def gather_rows(table: jnp.ndarray, ids: jnp.ndarray, *,
     ids : jnp.ndarray
         (N,) int32 row indices; -1 / out-of-range ids yield zero rows.
     block : int, default BLOCK_ROWS
-        Rows per grid step (the wrapper pads N up to a multiple).
-    interpret : bool, default True
-        Run the kernel body in interpret mode (CPU CI); pass False on
-        TPU deployments.
+        Rows per grid step (the wrapper pads N up to a multiple).  The
+        TPU compiler needs a multiple of 1024; interpret mode takes any.
+    interpret : bool, optional
+        Run the kernel body in interpret mode; ``None`` interprets only
+        on the CPU backend (``repro.kernels.resolve_interpret``).
 
     Returns
     -------
@@ -119,24 +120,28 @@ def gather_rows(table: jnp.ndarray, ids: jnp.ndarray, *,
     K, D = table.shape
     if N == 0 or K == 0:
         return jnp.zeros((N, D), table.dtype)
-    padded = ((N + block - 1) // block) * block
+    padded = -(-N // block) * block
     ids_p = jnp.concatenate(
         [ids.astype(jnp.int32),
          jnp.full((padded - N,), -1, jnp.int32)])
+    d_pad = -(-D // LANES) * LANES
+    table_p = jnp.pad(table, ((0, 0), (0, d_pad - D)))
 
-    kernel = functools.partial(_gather_kernel, block=block,
-                               num_ids=padded, table_rows=K)
-    out = pl.pallas_call(
+    kernel = functools.partial(_gather_kernel, block=block, table_rows=K,
+                               width=D)
+    return pl.pallas_call(
         kernel,
         grid=(padded // block,),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),    # ids    (HBM)
-            pl.BlockSpec(memory_space=pl.ANY),    # table  (HBM)
+            pl.BlockSpec((block,), lambda i: (i,),
+                         memory_space=pltpu.SMEM),    # ids
+            pl.BlockSpec(memory_space=pl.ANY),        # table (HBM)
         ],
         out_specs=pl.BlockSpec((block, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded, D), table.dtype),
-        scratch_shapes=[pltpu.VMEM((2, 1, D), table.dtype),
-                        pltpu.SemaphoreType.DMA((2,))],
-        interpret=interpret,
-    )(ids_p, table)
-    return out[:N]
+        scratch_shapes=[pltpu.VMEM((block, d_pad), table.dtype),
+                        pltpu.SemaphoreType.DMA((1,))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=resolve_interpret(interpret),
+    )(ids_p, table_p)[:N]

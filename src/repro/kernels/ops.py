@@ -1,14 +1,10 @@
-"""Jit'd public wrappers dispatching to the Pallas kernels.
+"""Public wrappers dispatching to the Pallas kernels.
 
-On this CPU container kernels run with ``interpret=True`` (the kernel body
-executed exactly as written); on TPU the same pallas_calls compile natively.
-Set ``REPRO_KERNEL_INTERPRET=0`` in a TPU deployment.
+Kernels run compiled on an accelerator and in interpret mode on the CPU
+backend (``repro.kernels.resolve_interpret``, decided at trace time).
 """
 from __future__ import annotations
 
-import os
-
-import jax
 import jax.numpy as jnp
 
 from repro.core.graph import CSCGraph
@@ -16,8 +12,6 @@ from repro.core.mfg import MFG
 from repro.core.sampler import build_indptr, register_backend, relabel
 from repro.kernels import fused_sample as _fs
 from repro.kernels import sage_aggregate as _agg
-
-INTERPRET = os.environ.get("REPRO_KERNEL_INTERPRET", "1") != "0"
 
 
 def fused_sample(graph: CSCGraph, seeds: jnp.ndarray, fanout: int, salt,
@@ -30,7 +24,7 @@ def fused_sample(graph: CSCGraph, seeds: jnp.ndarray, fanout: int, salt,
     """
     return _fs.fused_sample(graph.indptr, graph.indices, seeds,
                             jnp.asarray(salt, jnp.uint32), fanout=fanout,
-                            window=window, interpret=INTERPRET)
+                            window=window)
 
 
 def fused_sample_level(graph: CSCGraph, seeds: jnp.ndarray, fanout: int,
@@ -72,12 +66,11 @@ def sage_aggregate(mfg: MFG, h_src: jnp.ndarray, *, tile_s: int = 128,
     """Kernel-backed masked neighbor-mean (same contract as
     ``repro.core.mfg.mean_aggregate``)."""
     return _agg.sage_aggregate(mfg.edges, h_src, tile_s=tile_s,
-                               tile_n=tile_n, interpret=INTERPRET)
+                               tile_n=tile_n)
 
 
 def feature_gather(ids: jnp.ndarray, table: jnp.ndarray, *,
                    tile_i: int = 128, tile_t: int = 128) -> jnp.ndarray:
     """Kernel-backed row gather (hybrid feature-fetch payload hot-spot)."""
     from repro.kernels import feature_gather as _fg
-    return _fg.feature_gather(ids, table, tile_i=tile_i, tile_t=tile_t,
-                              interpret=INTERPRET)
+    return _fg.feature_gather(ids, table, tile_i=tile_i, tile_t=tile_t)

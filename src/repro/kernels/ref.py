@@ -1,7 +1,7 @@
 """Pure-jnp oracles for the Pallas kernels (the allclose ground truth).
 
 Each kernel in this package has its exact reference here; tests sweep shapes
-and dtypes and assert the kernel (interpret=True on CPU) matches.
+and dtypes and assert the kernel (interpreted on CPU) matches.
 """
 from __future__ import annotations
 
@@ -23,16 +23,9 @@ def ref_fused_sample(graph: CSCGraph, seeds: jnp.ndarray, fanout: int,
     return samples, build_indptr(valid)
 
 
-def ref_windowed_fused_sample(graph: CSCGraph, seeds: jnp.ndarray,
-                              fanout: int, salt, window: int):
-    """Window-clamped oracle for kernels.fused_sample.
-
-    The kernel streams at most ``window`` neighbors per seed into VMEM, so
-    hub draws are uniform over the *first* ``window`` entries of the
-    in-edge list.  This oracle truncates every neighbor list to ``window``
-    and reruns the exact reference draw — the kernel must match it
-    bit-for-bit — and also returns the expected ``overflow_count``.
-    """
+def window_truncated_graph(graph: CSCGraph, window: int) -> CSCGraph:
+    """``graph`` with every in-edge list cut to its first ``window``
+    entries — the neighbor set the fused kernel draws a hub from."""
     import numpy as np
 
     indptr = np.asarray(graph.indptr)
@@ -43,10 +36,25 @@ def ref_windowed_fused_sample(graph: CSCGraph, seeds: jnp.ndarray,
     np.cumsum(wdeg, out=windptr[1:])
     pos_in_row = np.arange(indices.size) - np.repeat(indptr[:-1], deg)
     windices = indices[pos_in_row < np.repeat(wdeg, deg)]
+    return CSCGraph(indptr=jnp.asarray(windptr, jnp.int32),
+                    indices=jnp.asarray(windices, jnp.int32))
 
-    truncated = CSCGraph(indptr=jnp.asarray(windptr, jnp.int32),
-                         indices=jnp.asarray(windices, jnp.int32))
-    samples, r = ref_fused_sample(truncated, seeds, fanout, salt)
+
+def ref_windowed_fused_sample(graph: CSCGraph, seeds: jnp.ndarray,
+                              fanout: int, salt, window: int):
+    """Window-clamped oracle for kernels.fused_sample.
+
+    The kernel draws a hub's neighbors uniformly from the *first*
+    ``window`` entries of its in-edge list.  This oracle truncates every
+    neighbor list to ``window`` and reruns the exact reference draw — the
+    kernel must match it bit-for-bit — and also returns the expected
+    ``overflow_count``.
+    """
+    import numpy as np
+
+    samples, r = ref_fused_sample(window_truncated_graph(graph, window),
+                                  seeds, fanout, salt)
+    deg = np.diff(np.asarray(graph.indptr))
     s_np = np.asarray(seeds)
     overflow = int((deg[s_np[s_np >= 0]] > window).sum())
     return samples, r, overflow

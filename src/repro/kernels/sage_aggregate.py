@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 TILE_S = 128      # dst rows per block
 TILE_N = 128      # src rows per block
 
@@ -62,7 +64,7 @@ def _sage_aggregate_kernel(edges_ref, hsrc_ref, out_ref, *, num_src_tiles):
                    static_argnames=("tile_s", "tile_n", "interpret"))
 def sage_aggregate(edges: jnp.ndarray, h_src: jnp.ndarray, *,
                    tile_s: int = TILE_S, tile_n: int = TILE_N,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: bool | None = None) -> jnp.ndarray:
     """Masked mean of h_src rows per dst: edges (S, F) int32 [-1 invalid],
     h_src (N, D) -> (S, D)."""
     S, F = edges.shape
@@ -85,6 +87,6 @@ def sage_aggregate(edges: jnp.ndarray, h_src: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((tile_s, D), lambda i, t: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((S_pad, D), h_src.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(edges_p, h_p)
     return out[:S]

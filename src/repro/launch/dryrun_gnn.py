@@ -46,7 +46,6 @@ def main():
     from jax.sharding import PartitionSpec as P
 
     from repro import roofline
-    from repro.compat import make_mesh, shard_map
     from repro.core import dist
     from repro.models.gnn import GNNConfig, gnn_loss, init_gnn_params
     from repro.pipeline import PipelineSpec
@@ -82,7 +81,8 @@ def main():
     def loss_fn(p, mfgs, h_src, labels, valid):
         return gnn_loss(p, mfgs, h_src, labels, valid, cfg)
 
-    mesh = make_mesh((W,), (dist.AXIS,))
+    mesh = jax.make_mesh((W,), (dist.AXIS,),
+                         (jax.sharding.AxisType.Auto,))
 
     schemes = ["vanilla", "hybrid"] if args.scheme == "both" \
         else [args.scheme]
@@ -108,11 +108,11 @@ def main():
                                            seeds_[0], gi, gx)
             return loss, grads
 
-        smap = shard_map(
+        smap = jax.shard_map(
             wrapper, mesh=mesh,
             in_specs=(P(), P(dist.AXIS), P(dist.AXIS), P(), P()),
             out_specs=(P(), P()),
-            check=False)
+            check_vma=False)
 
         with mesh:
             lowered = jax.jit(smap).lower(params, shards, seeds,

@@ -7,7 +7,10 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh
+
+def make_mesh(shape, axes):
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         (jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, model_parallel: int = 16):

@@ -12,6 +12,10 @@ stdout/stderr to per-rank log files, and supervises the fleet:
     (the failure mode ``tests/test_multihost.py`` provokes on purpose);
   * a wall-clock ``timeout`` bounds the whole run (hang detection).
 
+Every rank runs on the CPU backend (``JAX_PLATFORMS=cpu``, set by
+``rank_env``), also on a host with accelerators: a chip belongs to one
+process at a time.
+
 Workers call ``init_from_env()`` before any JAX work: it selects the
 gloo CPU collectives implementation (XLA's default CPU backend cannot
 run cross-process collectives) and calls ``jax.distributed.initialize``
@@ -36,7 +40,7 @@ ENV_NUM_PROCS = "REPRO_MH_NUM_PROCS"
 ENV_RANK = "REPRO_MH_RANK"
 ENV_LOCAL_DEVICES = "REPRO_MH_LOCAL_DEVICES"
 
-_DEVICE_FLAG = "--xla_force_host_platform_device_count"
+DEVICE_FLAG = "--xla_force_host_platform_device_count"
 
 
 class WorkerFailure(RuntimeError):
@@ -60,18 +64,22 @@ def pick_port() -> int:
 
 def rank_env(base_env: dict, *, rank: int, num_procs: int, port: int,
              local_devices: int = 1) -> dict:
-    """The environment for worker ``rank``: ``REPRO_MH_*`` wiring plus an
+    """The environment for worker ``rank``: ``REPRO_MH_*`` wiring, an
     ``XLA_FLAGS`` host-device count (replacing any pre-existing
     ``--xla_force_host_platform_device_count`` so the launcher's count
-    wins)."""
+    wins) and ``JAX_PLATFORMS=cpu``.  The fleet is a CPU simulation of
+    a multi-host job: on a chip host, ranks that reached for the chip
+    would race each other (and the parent) for it.  The path across
+    chips is the ``shard_map`` executor in one process."""
     env = dict(base_env)
+    env["JAX_PLATFORMS"] = "cpu"
     env[ENV_COORDINATOR] = f"127.0.0.1:{port}"
     env[ENV_NUM_PROCS] = str(num_procs)
     env[ENV_RANK] = str(rank)
     env[ENV_LOCAL_DEVICES] = str(local_devices)
     flags = [f for f in env.get("XLA_FLAGS", "").split()
-             if not f.startswith(_DEVICE_FLAG)]
-    flags.append(f"{_DEVICE_FLAG}={local_devices}")
+             if not f.startswith(DEVICE_FLAG)]
+    flags.append(f"{DEVICE_FLAG}={local_devices}")
     env["XLA_FLAGS"] = " ".join(flags)
     return env
 

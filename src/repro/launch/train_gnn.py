@@ -139,7 +139,9 @@ def main():
         if args.trace:
             multihost.merge_rank_traces(args.trace, args.num_procs)
             print(f"merged fleet trace written to {args.trace}")
-        print(f"multiprocess run complete; per-rank logs in {log_dir}")
+        print(f"multiprocess run complete ({args.num_procs} CPU "
+              f"processes over gloo; the chip path is --executor "
+              f"shard_map); per-rank logs in {log_dir}")
         return
 
     rank, local_parts = 0, None
@@ -154,10 +156,18 @@ def main():
         if args.cache_capacity == 0 and args.feature_store != "staged":
             local_parts = (rank * per, (rank + 1) * per)
     elif executor == "shard_map":
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
+        # placeholder devices for a CPU mesh, merged into any XLA_FLAGS
+        # the caller set (multihost.rank_env merges the same way)
+        flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
+                 if not f.startswith(multihost.DEVICE_FLAG)]
+        os.environ["XLA_FLAGS"] = " ".join(
+            flags + [f"{multihost.DEVICE_FLAG}={args.devices}"])
 
     import jax
+
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.obs import trace as obs_trace
 

@@ -98,30 +98,37 @@ def profile_stages(pipeline, loss_fn, params, *, batch: int,
     # profiler needs the three stages as three separate programs, which
     # no executor runner exposes (their whole point is fusing/overlapping
     # them)
+    pipeline.place_data(None)
     use_cache = pipeline.cache is not None
     cache_ax = 0 if use_cache else None
-    vprep = jax.vmap(prepare, in_axes=(0, 0, None, cache_ax),
+    shard_ax = pipeline.shards.axes(0, None)
+    vprep = jax.vmap(prepare, in_axes=(shard_ax, 0, None, cache_ax),
                      axis_name=dist.AXIS)
-    vfetch = jax.vmap(fetch, in_axes=(0, 0, cache_ax),
+    vfetch = jax.vmap(fetch, in_axes=(shard_ax, 0, cache_ax),
                       axis_name=dist.AXIS)
-    vcons = jax.vmap(consume, in_axes=(None, 0, 0, cache_ax),
+    vcons = jax.vmap(consume, in_axes=(None, shard_ax, 0, cache_ax),
                      axis_name=dist.AXIS)
-    shards, cache = pipeline.shards, pipeline.cache
+    # the data enters each program as an argument, not a baked constant
+    data = (pipeline.shards, pipeline.cache)
 
     @jax.jit
-    def sample_j(seeds, salt):
-        return vprep(shards, seeds, salt, cache)
+    def sample_raw(seeds, salt, data):
+        return vprep(data[0], seeds, salt, data[1])
 
     @jax.jit
-    def fetch_j(batch):
-        return vfetch(shards, batch, cache)
+    def fetch_raw(batch, data):
+        return vfetch(data[0], batch, data[1])
 
     @jax.jit
-    def compute_j(params, batch):
+    def compute_raw(params, batch, data):
         take0 = lambda x: x[0]
-        loss, grads, metrics = vcons(params, shards, batch, cache)
+        loss, grads, metrics = vcons(params, data[0], batch, data[1])
         return loss[0], jax.tree.map(take0, grads), \
             jax.tree.map(take0, metrics)
+
+    sample_j = lambda seeds, salt: sample_raw(seeds, salt, data)
+    fetch_j = lambda batch: fetch_raw(batch, data)
+    compute_j = lambda params, batch: compute_raw(params, batch, data)
 
     stream = SeedStream(pipeline, batch,
                         strategy=pipeline.spec.prefetch.seed_stream,

@@ -6,9 +6,12 @@ every launcher; here it is a registry of executors sharing one contract:
     executor.bind(pipeline, step) -> run(params, seeds, salt)
         -> (loss, grads, metrics)
 
-where ``step`` is a ``repro.pipeline.worker`` step.  Both executors bind
-the pipeline's shards (and cache, when present) so callers only supply
-the per-call arguments.
+where ``step`` is a ``repro.pipeline.worker`` step.  Every executor
+places the pipeline's data (shards, replicated topology, cache) on its
+devices once (``Pipeline.place_data``) and binds it, so callers only
+supply the per-call arguments; ``run.with_data`` / ``run.data`` let an
+outer jit take that data as an argument instead of baking it into the
+compiled program as a constant.
 
   * ``"vmap"``         — single-device simulation: vmap over the stacked
                          worker axis; bit-identical collective semantics.
@@ -190,6 +193,24 @@ def _require_full_layout(executor, pipeline):
             f"'multiprocess' executor, or build with local_parts=None.")
 
 
+def _bound(with_data, data):
+    """``run(params, seeds, salt)`` over the placed ``data``, carrying
+    the ``with_data(params, seeds, salt, data)`` twin that outer jits
+    (``Pipeline.train_step``) call so the data enters the program as an
+    argument: closed over, it would be baked into the compiled program
+    as a constant (gigabytes at the paper's graph sizes)."""
+    def run(params, seeds, salt):
+        return with_data(params, seeds, salt, data)
+
+    run.with_data = with_data
+    run.data = data
+    return run
+
+
+def _take0(tree):
+    return jax.tree.map(lambda x: x[0], tree)
+
+
 class VmapExecutor:
     """Single-device simulation: vmap over the stacked worker axis.
 
@@ -209,6 +230,24 @@ class VmapExecutor:
         (commit to the default device) is already optimal."""
         return None
 
+    def _vmapped(self, pipeline, fn):
+        """vmap ``fn(params, shard, seeds, salt[, cache])`` over the
+        worker axis after placing the pipeline's data on the default
+        device: returns ``(call(params, seeds, salt, data), data)``."""
+        _require_full_layout(self, pipeline)
+        pipeline.place_data(None)
+        use_cache = pipeline.cache is not None
+        in_axes = (None, pipeline.shards.axes(0, None), 0, None) \
+            + ((0,) if use_cache else ())
+        vfn = jax.vmap(fn, in_axes=in_axes, axis_name=dist.AXIS)
+
+        def call(params, seeds, salt, data):
+            shards, cache = data
+            args = (params, shards, seeds, salt)
+            return vfn(*(args + ((cache,) if use_cache else ())))
+
+        return call, (pipeline.shards, pipeline.cache)
+
     def bind(self, pipeline, step):
         """Bind ``step`` (a ``repro.pipeline.worker`` program) to the
         pipeline's shards/cache under ``jax.vmap``.
@@ -216,22 +255,14 @@ class VmapExecutor:
         Returns ``run(params, seeds, salt) -> (loss, grads, metrics)``
         with the worker axis already reduced (worker 0's pmean-ed copy).
         """
-        _require_full_layout(self, pipeline)
-        use_cache = pipeline.cache is not None
-        in_axes = (None, 0, 0, None) + ((0,) if use_cache else ())
-        vstep = jax.vmap(step, in_axes=in_axes, axis_name=dist.AXIS)
+        call, data = self._vmapped(pipeline, step)
 
-        def run(params, seeds, salt):
-            args = (params, pipeline.shards, seeds, salt)
-            if use_cache:
-                args += (pipeline.cache,)
-            loss, grads, metrics = vstep(*args)
+        def with_data(params, seeds, salt, data):
+            loss, grads, metrics = call(params, seeds, salt, data)
             # pmean makes every worker's copy identical; take worker 0's
-            take0 = lambda x: x[0]
-            return loss[0], jax.tree.map(take0, grads), \
-                jax.tree.map(take0, metrics)
+            return loss[0], _take0(grads), _take0(metrics)
 
-        return run
+        return _bound(with_data, data)
 
     def bind_infer(self, pipeline, infer_step):
         """Bind an inference step (``repro.pipeline.infer``) under vmap.
@@ -242,19 +273,13 @@ class VmapExecutor:
         owning worker's row.  ``metrics`` is already pmean/psum-reduced
         inside the step, so worker 0's copy is returned.
         """
-        _require_full_layout(self, pipeline)
-        use_cache = pipeline.cache is not None
-        in_axes = (None, 0, 0, None) + ((0,) if use_cache else ())
-        vstep = jax.vmap(infer_step, in_axes=in_axes, axis_name=dist.AXIS)
+        call, data = self._vmapped(pipeline, infer_step)
 
-        def run(params, seeds, salt):
-            args = (params, pipeline.shards, seeds, salt)
-            if use_cache:
-                args += (pipeline.cache,)
-            logits, metrics = vstep(*args)
-            return logits, jax.tree.map(lambda x: x[0], metrics)
+        def with_data(params, seeds, salt, data):
+            logits, metrics = call(params, seeds, salt, data)
+            return logits, _take0(metrics)
 
-        return run
+        return _bound(with_data, data)
 
     def bind_prefetch(self, pipeline, prepare, prepare_warm, consume,
                       update):
@@ -270,34 +295,38 @@ class VmapExecutor:
         and therefore ticks the round counter, exactly once).
         """
         _require_full_layout(self, pipeline)
+        pipeline.place_data(None)
         use_cache = pipeline.cache is not None
         cache_ax = 0 if use_cache else None
+        shard_ax = pipeline.shards.axes(0, None)
         # feature stores with external_rows (the "staged" store) do NOT
         # thread their (P, src_capacity, D) rows through this prepare —
         # the runner attaches them to the batch host-side and the consume
         # fetches from them (see _AsyncDispatchRunner)
-        vprep = jax.vmap(prepare, in_axes=(0, 0, None, cache_ax),
+        vprep = jax.vmap(prepare, in_axes=(shard_ax, 0, None, cache_ax),
                          axis_name=dist.AXIS)
-        vcons = jax.vmap(consume, in_axes=(None, 0, 0, cache_ax),
+        vcons = jax.vmap(consume, in_axes=(None, shard_ax, 0, cache_ax),
                          axis_name=dist.AXIS)
-        shards, cache = pipeline.shards, pipeline.cache
+        data = (pipeline.shards, pipeline.cache)
 
         @jax.jit
-        def prepare_j(seeds, salt):
+        def prepare_raw(seeds, salt, data):
+            shards, cache = data
             return vprep(shards, seeds, salt, cache)
 
         @jax.jit
-        def consume_j(params, opt_state, batch):
-            take0 = lambda x: x[0]
+        def consume_raw(params, opt_state, batch, data):
+            shards, cache = data
             loss, grads, metrics = vcons(params, shards, batch, cache)
-            loss = loss[0]
-            grads = jax.tree.map(take0, grads)
-            metrics = jax.tree.map(take0, metrics)
-            params, opt_state, metrics = update(params, opt_state, grads,
-                                                metrics)
-            return params, opt_state, loss, metrics
+            params, opt_state, metrics = update(params, opt_state,
+                                                _take0(grads),
+                                                _take0(metrics))
+            return params, opt_state, loss[0], metrics
 
-        return _AsyncDispatchRunner(prepare_j, consume_j)
+        return _AsyncDispatchRunner(
+            lambda seeds, salt: prepare_raw(seeds, salt, data),
+            lambda params, opt_state, batch: consume_raw(
+                params, opt_state, batch, data))
 
 
 class ShardMapExecutor:
@@ -305,7 +334,10 @@ class ShardMapExecutor:
 
     ``mesh`` defaults to a fresh 1-D mesh of ``num_parts`` devices along
     ``dist.AXIS`` (pass an existing mesh to embed the worker axis in a
-    larger topology).
+    larger topology).  The pipeline's data is placed on the mesh once at
+    bind time — per-worker arrays sharded along the worker axis, the
+    replicated topology copied to every device — and enters each jitted
+    program as an argument.
     """
 
     name = "shard_map"
@@ -326,8 +358,6 @@ class ShardMapExecutor:
         return NamedSharding(self._resolve_mesh(pipeline), P(dist.AXIS))
 
     def _resolve_mesh(self, pipeline):
-        from repro.compat import make_mesh
-
         _require_full_layout(self, pipeline)
         num_parts = pipeline.spec.plan.num_parts
         mesh = self.mesh
@@ -338,43 +368,48 @@ class ShardMapExecutor:
                     f"found {len(jax.devices())} (set "
                     f"--xla_force_host_platform_device_count for a CPU "
                     f"placeholder mesh)")
-            mesh = make_mesh((num_parts,), (dist.AXIS,))
+            mesh = jax.make_mesh((num_parts,), (dist.AXIS,),
+                                 (jax.sharding.AxisType.Auto,))
+            self.mesh = mesh
         return mesh
 
-    def _build_smap(self, pipeline, step):
-        """The shard_map program for the fused train step, taking the
-        worker-axis data (shards [+ cache]) as explicit *arguments* —
-        ``(smap, use_cache)`` where ``smap(params, shards, seeds[,
-        cache], salt)``.  Shared by the closure-binding single-process
-        ``bind`` and the argument-threading multi-process one (global
-        arrays may not be closed over inside jit)."""
+    def _placed(self, pipeline):
+        """The mesh, after placing the pipeline's data on it."""
+        mesh = self._resolve_mesh(pipeline)
+        pipeline.place_data(mesh)
+        return mesh
+
+    def _smap(self, pipeline, fn, *, per_worker_out):
+        """shard_map ``fn(params, shard, seeds, salt[, cache])`` over the
+        worker axis, with the data as arguments: returns ``call(params,
+        seeds, salt, data)``.  With ``per_worker_out`` the first output
+        keeps the worker axis (the inference logits), else every output
+        is replicated."""
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
-
-        mesh = self._resolve_mesh(pipeline)
+        mesh = self._placed(pipeline)
         use_cache = pipeline.cache is not None
-        squeeze = lambda t: jax.tree.map(lambda a: a[0], t)
+        A = dist.AXIS
 
-        if use_cache:
-            def wrapper(params, shards, seeds, cache, salt):
-                return step(params, squeeze(shards), seeds[0], salt,
-                            squeeze(cache))
+        def wrapper(params, shards, seeds, salt, cache):
+            cache_ = (_take0(cache),) if use_cache else ()
+            out = fn(params, shards.worker(0), seeds[0], salt, *cache_)
+            if per_worker_out:
+                out = (out[0][None],) + tuple(out[1:])
+            return out
 
-            smap = shard_map(
-                wrapper, mesh=mesh,
-                in_specs=(P(), P(dist.AXIS), P(dist.AXIS), P(dist.AXIS),
-                          P()),
-                out_specs=(P(), P(), P()), check=False)
-        else:
-            def wrapper(params, shards, seeds, salt):
-                return step(params, squeeze(shards), seeds[0], salt)
+        out_specs = (P(A), P()) if per_worker_out else (P(), P(), P())
+        smap = jax.shard_map(
+            wrapper, mesh=mesh,
+            in_specs=(P(), pipeline.shards.axes(P(A), P()), P(A), P(),
+                      P(A)),
+            out_specs=out_specs, check_vma=False)
 
-            smap = shard_map(
-                wrapper, mesh=mesh,
-                in_specs=(P(), P(dist.AXIS), P(dist.AXIS), P()),
-                out_specs=(P(), P(), P()), check=False)
-        return smap, use_cache
+        def call(params, seeds, salt, data):
+            shards, cache = data
+            return smap(params, shards, seeds, salt, cache)
+
+        return call
 
     def bind(self, pipeline, step):
         """Bind ``step`` to the pipeline's shards/cache under ``shard_map``
@@ -383,53 +418,8 @@ class ShardMapExecutor:
         Returns ``run(params, seeds, salt) -> (loss, grads, metrics)``
         with replicated (pmean-ed) outputs.
         """
-        smap, use_cache = self._build_smap(pipeline, step)
-
-        if use_cache:
-            def run(params, seeds, salt):
-                return smap(params, pipeline.shards, seeds,
-                            pipeline.cache, salt)
-        else:
-            def run(params, seeds, salt):
-                return smap(params, pipeline.shards, seeds, salt)
-
-        return run
-
-    def _build_infer_smap(self, pipeline, infer_step):
-        """shard_map program for the inference step with data as
-        arguments: ``(smap, use_cache)`` where ``smap(params, shards,
-        seeds[, cache], salt) -> (logits, metrics)``."""
-        from jax.sharding import PartitionSpec as P
-
-        from repro.compat import shard_map
-
-        mesh = self._resolve_mesh(pipeline)
-        use_cache = pipeline.cache is not None
-        squeeze = lambda t: jax.tree.map(lambda a: a[0], t)
-
-        if use_cache:
-            def wrapper(params, shards, seeds, cache, salt):
-                logits, metrics = infer_step(params, squeeze(shards),
-                                             seeds[0], salt,
-                                             squeeze(cache))
-                return logits[None], metrics
-
-            smap = shard_map(
-                wrapper, mesh=mesh,
-                in_specs=(P(), P(dist.AXIS), P(dist.AXIS), P(dist.AXIS),
-                          P()),
-                out_specs=(P(dist.AXIS), P()), check=False)
-        else:
-            def wrapper(params, shards, seeds, salt):
-                logits, metrics = infer_step(params, squeeze(shards),
-                                             seeds[0], salt)
-                return logits[None], metrics
-
-            smap = shard_map(
-                wrapper, mesh=mesh,
-                in_specs=(P(), P(dist.AXIS), P(dist.AXIS), P()),
-                out_specs=(P(dist.AXIS), P()), check=False)
-        return smap, use_cache
+        call = self._smap(pipeline, step, per_worker_out=False)
+        return _bound(call, (pipeline.shards, pipeline.cache))
 
     def bind_infer(self, pipeline, infer_step):
         """Bind an inference step (``repro.pipeline.infer``) under
@@ -440,17 +430,8 @@ class ShardMapExecutor:
         its own seeds' logits); ``metrics`` is replicated (the step
         pmean/psums it over ``dist.AXIS``).
         """
-        smap, use_cache = self._build_infer_smap(pipeline, infer_step)
-
-        if use_cache:
-            def run(params, seeds, salt):
-                return smap(params, pipeline.shards, seeds,
-                            pipeline.cache, salt)
-        else:
-            def run(params, seeds, salt):
-                return smap(params, pipeline.shards, seeds, salt)
-
-        return run
+        call = self._smap(pipeline, infer_step, per_worker_out=True)
+        return _bound(call, (pipeline.shards, pipeline.cache))
 
     def bind_prefetch(self, pipeline, prepare, prepare_warm, consume,
                       update):
@@ -468,110 +449,90 @@ class ShardMapExecutor:
         scheduled against the consume's compute.  ``prepare_warm`` (an
         uncounted twin of ``prepare``) fills the queue initially from a
         separate jit so warmup traces don't inflate the pipeline's
-        RoundCounter.
+        RoundCounter.  The placed data is passed after the donated queue,
+        so only the queue's buffers rotate.
         """
         from functools import partial
 
-        smap_prep, smap_prep_warm, smap_cons, use_cache = \
-            self._build_prefetch_smaps(pipeline, prepare, prepare_warm,
-                                       consume)
-        shards, cache = pipeline.shards, pipeline.cache
-
-        def _call_prep(smap, seeds, salt, *rest):
-            args = (shards, seeds)
-            if use_cache:
-                args += (cache,)
-            args += tuple(rest) + (salt,)
-            return smap(*args)
-
-        def _consume(params, batch):
-            if use_cache:
-                return smap_cons(params, batch, shards, cache)
-            return smap_cons(params, batch, shards)
+        smap_prep, smap_prep_warm, smap_cons = self._build_prefetch_smaps(
+            pipeline, prepare, prepare_warm, consume)
+        data = (pipeline.shards, pipeline.cache)
 
         @partial(jax.jit, donate_argnums=(2,))
-        def fused_j(params, opt_state, queue, seeds_next, salt_next,
-                    *rest):
-            loss, grads, metrics = _consume(params, queue[0])
+        def fused_raw(params, opt_state, queue, seeds_next, salt_next,
+                      rest, data):
+            loss, grads, metrics = smap_cons(params, queue[0], data)
             params, opt_state, metrics = update(params, opt_state, grads,
                                                 metrics)
-            nxt = _call_prep(smap_prep, seeds_next, salt_next, *rest)
+            nxt = smap_prep(seeds_next, salt_next, rest, data)
             return params, opt_state, loss, metrics, queue[1:] + (nxt,)
 
         @jax.jit
+        def warm_raw(seeds, salt, rest, data):
+            return smap_prep_warm(seeds, salt, rest, data)
+
         def warm_j(seeds, salt, *rest):
-            return _call_prep(smap_prep_warm, seeds, salt, *rest)
+            return warm_raw(seeds, salt, tuple(rest), data)
+
+        def fused_j(params, opt_state, queue, seeds_next, salt_next,
+                    *rest):
+            params, opt_state = pipeline.replicate((params, opt_state))
+            return fused_raw(params, opt_state, queue, seeds_next,
+                             salt_next, tuple(rest), data)
 
         return _RotatingBufferRunner(warm_j, fused_j)
 
     def _build_prefetch_smaps(self, pipeline, prepare, prepare_warm,
                               consume):
-        """shard_map programs for the split step with the worker-axis
-        data as explicit arguments: ``(smap_prep, smap_prep_warm,
-        smap_cons, use_cache)`` where the prepares take ``(shards,
-        seeds[, cache], salt)`` and the consume ``(params, batch,
-        shards[, cache])``.  Shared with ``MultiprocessExecutor``, whose
-        jits must receive global arrays as arguments, never closures."""
+        """shard_map programs for the split step with the placed data as
+        an argument: ``(smap_prep, smap_prep_warm, smap_cons)`` where the
+        prepares take ``(seeds, salt, rest, data)`` — ``rest`` holding
+        the staged feature rows of ``external_rows`` stores, else empty —
+        and the consume ``(params, batch, data)``."""
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
-
-        mesh = self._resolve_mesh(pipeline)
+        mesh = self._placed(pipeline)
         use_cache = pipeline.cache is not None
         ext = bool(getattr(getattr(pipeline, "feature_store", None),
                            "external_rows", False))
-        squeeze = lambda t: jax.tree.map(lambda a: a[0], t)
         expand = lambda t: jax.tree.map(lambda a: a[None], t)
         A = dist.AXIS
+        shard_spec = pipeline.shards.axes(P(A), P())
 
-        # positional layout (shards, seeds[, cache][, staged], salt):
-        # worker-axis data first, then the optional staged feature rows
-        # (stores with external_rows), replicated salt last
         def _smap_prepare(fn):
-            def wrapper(*args):
-                shards_, seeds = args[0], args[1]
-                i = 2
-                cache_ = None
-                if use_cache:
-                    cache_ = squeeze(args[i])
-                    i += 1
-                staged_ = None
-                if ext:
-                    staged_ = args[i][0]
-                    i += 1
-                salt = args[i]
-                return expand(fn(squeeze(shards_), seeds[0], salt,
-                                 cache_, staged_))
+            def wrapper(shards, cache, seeds, staged, salt):
+                cache_ = _take0(cache) if use_cache else None
+                staged_ = staged[0] if ext else None
+                return expand(fn(shards.worker(0), seeds[0], salt, cache_,
+                                 staged_))
 
-            specs = [P(A), P(A)] + ([P(A)] if use_cache else []) \
-                + ([P(A)] if ext else []) + [P()]
-            return shard_map(
-                wrapper, mesh=mesh, in_specs=tuple(specs),
-                out_specs=P(A), check=False)
+            smap = jax.shard_map(
+                wrapper, mesh=mesh,
+                in_specs=(shard_spec, P(A), P(A), P(A), P()),
+                out_specs=P(A), check_vma=False)
 
-        smap_prep = _smap_prepare(prepare)
-        smap_prep_warm = _smap_prepare(prepare_warm)
+            def call(seeds, salt, rest, data):
+                shards, cache = data
+                staged = rest[0] if ext else None
+                return smap(shards, cache, seeds, staged, salt)
 
-        if use_cache:
-            def cons_wrapper(params, batch, shards_, cache_):
-                return consume(params, squeeze(shards_), squeeze(batch),
-                               squeeze(cache_))
+            return call
 
-            smap_cons = shard_map(
-                cons_wrapper, mesh=mesh,
-                in_specs=(P(), P(A), P(A), P(A)),
-                out_specs=(P(), P(), P()), check=False)
-        else:
-            def cons_wrapper(params, batch, shards_):
-                return consume(params, squeeze(shards_), squeeze(batch),
-                               None)
+        def cons_wrapper(params, batch, shards, cache):
+            cache_ = _take0(cache) if use_cache else None
+            return consume(params, shards.worker(0), _take0(batch), cache_)
 
-            smap_cons = shard_map(
-                cons_wrapper, mesh=mesh,
-                in_specs=(P(), P(A), P(A)),
-                out_specs=(P(), P(), P()), check=False)
+        smap_cons = jax.shard_map(
+            cons_wrapper, mesh=mesh,
+            in_specs=(P(), P(A), shard_spec, P(A)),
+            out_specs=(P(), P(), P()), check_vma=False)
 
-        return smap_prep, smap_prep_warm, smap_cons, use_cache
+        def call_cons(params, batch, data):
+            shards, cache = data
+            return smap_cons(params, batch, shards, cache)
+
+        return (_smap_prepare(prepare), _smap_prepare(prepare_warm),
+                call_cons)
 
 
 class MultiprocessExecutor(ShardMapExecutor):
@@ -588,13 +549,13 @@ class MultiprocessExecutor(ShardMapExecutor):
 
     Two things differ from single-process shard_map:
 
-    * the pipeline's worker-axis arrays (shards, cache) are converted to
-      global arrays at bind time (``Pipeline.globalize_shards``): each
-      process contributes only its **addressable** rows, which is what
-      makes rank-local builds (``Pipeline.build(local_parts=...)``) safe
-      — a rank never materializes (or ships) partitions it doesn't own.
-      Params, opt state, seeds, and salt stay ordinary uncommitted host
-      arrays; JAX replicates/auto-shards them per the program's specs.
+    * the mesh spans processes, so ``Pipeline.place_data`` builds global
+      arrays in which each process contributes only its **addressable**
+      rows — which is what makes rank-local builds
+      (``Pipeline.build(local_parts=...)``) safe: a rank never
+      materializes (or ships) partitions it doesn't own.  Params, opt
+      state, seeds, and salt stay ordinary uncommitted host arrays; JAX
+      replicates/auto-shards them per the program's specs.
     * cross-process collectives run on the CPU backend's gloo
       implementation.  ``lax.all_to_all`` (the paper's communication
       primitive) is pure data movement and bit-exact everywhere; the
@@ -649,123 +610,6 @@ class MultiprocessExecutor(ShardMapExecutor):
                 f"rank-local layout covers partitions {tuple(lp)!r} but "
                 f"process {me} addresses mesh rows {want!r}; build with "
                 f"local_parts={want!r}")
-
-    def _globalize(self, pipeline):
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
-
-        mesh = self._resolve_mesh(pipeline)
-        pipeline.globalize_shards(NamedSharding(mesh, P(dist.AXIS)))
-
-    @staticmethod
-    def _data_of(pipeline, use_cache):
-        return ((pipeline.shards, pipeline.cache) if use_cache
-                else (pipeline.shards,))
-
-    def bind(self, pipeline, step):
-        """Like ``ShardMapExecutor.bind``, but the returned ``run``
-        carries a ``with_data(params, seeds, salt, data)`` twin plus the
-        bound ``data`` pytree: global arrays may not be *closed over*
-        inside jit, so outer jits (``Pipeline.train_step``) re-thread
-        them as arguments."""
-        self._globalize(pipeline)
-        smap, use_cache = self._build_smap(pipeline, step)
-
-        if use_cache:
-            def with_data(params, seeds, salt, data):
-                shards, cache = data
-                return smap(params, shards, seeds, cache, salt)
-        else:
-            def with_data(params, seeds, salt, data):
-                (shards,) = data
-                return smap(params, shards, seeds, salt)
-
-        data = self._data_of(pipeline, use_cache)
-
-        def run(params, seeds, salt):
-            return with_data(params, seeds, salt, data)
-
-        run.with_data = with_data
-        run.data = data
-        return run
-
-    def bind_infer(self, pipeline, infer_step):
-        """``ShardMapExecutor.bind_infer`` with the multi-process
-        data-as-arguments protocol (see ``bind``)."""
-        self._globalize(pipeline)
-        smap, use_cache = self._build_infer_smap(pipeline, infer_step)
-
-        if use_cache:
-            def with_data(params, seeds, salt, data):
-                shards, cache = data
-                return smap(params, shards, seeds, cache, salt)
-        else:
-            def with_data(params, seeds, salt, data):
-                (shards,) = data
-                return smap(params, shards, seeds, salt)
-
-        data = self._data_of(pipeline, use_cache)
-
-        def run(params, seeds, salt):
-            return with_data(params, seeds, salt, data)
-
-        run.with_data = with_data
-        run.data = data
-        return run
-
-    def bind_prefetch(self, pipeline, prepare, prepare_warm, consume,
-                      update):
-        """``ShardMapExecutor.bind_prefetch`` with the global shards and
-        cache passed into the fused jit as arguments each step (the
-        rotation/donation structure is unchanged; ``data`` is appended
-        after the donated queue, so only the queue's buffers rotate)."""
-        from functools import partial
-
-        self._globalize(pipeline)
-        smap_prep, smap_prep_warm, smap_cons, use_cache = \
-            self._build_prefetch_smaps(pipeline, prepare, prepare_warm,
-                                       consume)
-        data = self._data_of(pipeline, use_cache)
-
-        def _call_prep(smap, seeds, salt, rest, data):
-            if use_cache:
-                shards, cache = data
-                args = (shards, seeds, cache)
-            else:
-                (shards,) = data
-                args = (shards, seeds)
-            args += tuple(rest) + (salt,)
-            return smap(*args)
-
-        def _consume(params, batch, data):
-            if use_cache:
-                shards, cache = data
-                return smap_cons(params, batch, shards, cache)
-            (shards,) = data
-            return smap_cons(params, batch, shards)
-
-        @partial(jax.jit, donate_argnums=(2,))
-        def fused_raw(params, opt_state, queue, seeds_next, salt_next,
-                      rest, data):
-            loss, grads, metrics = _consume(params, queue[0], data)
-            params, opt_state, metrics = update(params, opt_state, grads,
-                                                metrics)
-            nxt = _call_prep(smap_prep, seeds_next, salt_next, rest, data)
-            return params, opt_state, loss, metrics, queue[1:] + (nxt,)
-
-        @jax.jit
-        def warm_raw(seeds, salt, rest, data):
-            return _call_prep(smap_prep_warm, seeds, salt, rest, data)
-
-        def warm_j(seeds, salt, *rest):
-            return warm_raw(seeds, salt, tuple(rest), data)
-
-        def fused_j(params, opt_state, queue, seeds_next, salt_next,
-                    *rest):
-            return fused_raw(params, opt_state, queue, seeds_next,
-                             salt_next, tuple(rest), data)
-
-        return _RotatingBufferRunner(warm_j, fused_j)
 
 
 register_executor("vmap", VmapExecutor)

@@ -61,7 +61,7 @@ class Pipeline:
     dataset: "GraphDataset | None" = None           # noqa: F821
     feature_store: "FeatureStore | None" = None     # noqa: F821
     _edge_cut: float | None = None
-    _global_sharding: object = None
+    _placed_on: object = None
 
     # ---------------------------------------------------------------- build
 
@@ -216,7 +216,8 @@ class Pipeline:
         shards = dist.WorkerShard(features=layout.features,
                                   labels=layout.labels,
                                   local_indptr=local_indptr,
-                                  local_indices=local_indices)
+                                  local_indices=local_indices,
+                                  replicated=placement.replicated_state())
 
         cache = None
         if plan.cache_capacity > 0:
@@ -395,15 +396,13 @@ class Pipeline:
             raise TypeError(
                 f"executor {getattr(executor, 'name', executor)!r} does "
                 f"not support inference binding (no bind_infer method)")
-        fn = bind(self, self.make_infer_step(forward_fn, counted=counted))
-        with_data = getattr(fn, "with_data", None)
-        if with_data is not None and jit:
-            # multi-process data-as-arguments protocol (see train_step)
-            data = fn.data
-            jfn = jax.jit(with_data)
-            return lambda params, seeds, salt: jfn(params, seeds, salt,
-                                                   data)
-        return jax.jit(fn) if jit else fn
+        run = bind(self, self.make_infer_step(forward_fn, counted=counted))
+        if not jit:
+            return run
+        # the placed data enters the program as an argument (see
+        # repro.pipeline.executor._bound)
+        jfn, data = jax.jit(run.with_data), run.data
+        return lambda params, seeds, salt: jfn(params, seeds, salt, data)
 
     def step_fn(self, loss_fn, executor=None):
         """Bind the fused step to the spec'd executor.
@@ -449,40 +448,24 @@ class Pipeline:
         run = self.step_fn(loss_fn, executor=executor)
         update = make_update_fn(lr=lr, optimizer=optimizer,
                                 grad_clip=grad_clip)
+        with_data, data = run.with_data, run.data
 
-        with_data = getattr(run, "with_data", None)
-        if with_data is not None:
-            # multi-process executor: global arrays may not be closed
-            # over inside jit — the bound data pytree is threaded through
-            # the jitted program as an argument instead
-            data = run.data
-
-            @jax.jit
-            def jfn(params, opt_state, seeds, salt, data):
-                loss, grads, metrics = with_data(params, seeds, salt,
-                                                 data)
-                params, opt_state, metrics = update(params, opt_state,
-                                                    grads, metrics)
-                return params, opt_state, loss, metrics
-
-            def fn(params, opt_state, seeds, salt):
-                return jfn(params, opt_state, seeds, salt, data)
-
-            if not jit:
-                def fn(params, opt_state, seeds, salt):      # noqa: F811
-                    loss, grads, metrics = run(params, seeds, salt)
-                    params, opt_state, metrics = update(
-                        params, opt_state, grads, metrics)
-                    return params, opt_state, loss, metrics
-            return fn
-
-        def fn(params, opt_state, seeds, salt):
-            loss, grads, metrics = run(params, seeds, salt)
+        def step(params, opt_state, seeds, salt, data):
+            loss, grads, metrics = with_data(params, seeds, salt, data)
             params, opt_state, metrics = update(params, opt_state, grads,
                                                 metrics)
             return params, opt_state, loss, metrics
 
-        return jax.jit(fn) if jit else fn
+        if jit:
+            # the placed data is an argument of the jitted program, never
+            # a constant baked into it
+            step = jax.jit(step)
+
+        def fn(params, opt_state, seeds, salt):
+            params, opt_state = self.replicate((params, opt_state))
+            return step(params, opt_state, seeds, salt, data)
+
+        return fn
 
     def train_driver(self, loss_fn, *, batch: int, lr: float = 1e-3,
                      optimizer: str = "adamw",
@@ -538,37 +521,65 @@ class Pipeline:
 
     # ------------------------------------------------------------ utilities
 
-    def globalize_shards(self, sharding) -> None:
-        """Convert ``shards`` (and ``cache``) into multi-process global
-        arrays sharded along the worker axis (idempotent).
+    def place_data(self, mesh=None) -> None:
+        """Put ``shards`` (and ``cache``) on devices, once per pipeline.
 
-        Called by the ``"multiprocess"`` executor at bind time:
-        ``sharding`` is a ``NamedSharding`` over the *global* device mesh
-        with ``PartitionSpec(dist.AXIS)`` on the leading (worker) axis.
-        Each process materializes only its **addressable** rows via
-        ``jax.make_array_from_callback`` — which is exactly what a
-        rank-local build (``local_parts``) filled; the zero rows a rank
-        never owns are never read.  Params/opt-state/seeds stay ordinary
-        uncommitted arrays (JAX replicates/auto-shards them), so only the
-        worker-axis data needs this conversion.
+        Executors call this at bind time and then pass the placed arrays
+        into every jitted program as arguments.  Without a ``mesh`` all
+        of it goes to the default device (the vmap executor).  With one
+        (a mesh whose ``dist.AXIS`` is the worker axis, possibly spanning
+        processes), the per-worker leaves are sharded along the worker
+        axis and ``shards.replicated`` is copied to every device; each
+        process materializes only its **addressable** rows via
+        ``jax.make_array_from_callback`` — exactly what a rank-local
+        build (``local_parts``) filled, so the zero rows a rank never
+        owns are never read (in one process a plain ``device_put``
+        does).  Params/opt-state/seeds stay ordinary arrays (JAX
+        replicates/auto-shards them).
         """
-        if self._global_sharding is not None:
-            if self._global_sharding != sharding:
+        if self._placed_on is not None:
+            if self._placed_on != ("mesh", mesh):
                 raise ValueError(
-                    "pipeline shards were already globalized with a "
-                    "different sharding; build a fresh Pipeline to bind "
-                    "a different mesh")
+                    "pipeline data was already placed for a different "
+                    "mesh; build a fresh Pipeline to bind another one")
             return
+        if mesh is None:
+            self.shards = jax.device_put(self.shards)
+            self.cache = jax.device_put(self.cache)
+        else:
+            from jax.sharding import NamedSharding
+            from jax.sharding import PartitionSpec as P
 
-        def to_global(leaf):
-            host = np.asarray(leaf)
-            return jax.make_array_from_callback(
-                host.shape, sharding, lambda idx, h=host: h[idx])
+            worker = NamedSharding(mesh, P(dist.AXIS))
+            whole = NamedSharding(mesh, P())
 
-        self.shards = jax.tree.map(to_global, self.shards)
-        if self.cache is not None:
-            self.cache = jax.tree.map(to_global, self.cache)
-        self._global_sharding = sharding
+            def put(sharding, tree):
+                def one(leaf):
+                    if jax.process_count() == 1:
+                        return jax.device_put(leaf, sharding)
+                    host = np.asarray(leaf)
+                    return jax.make_array_from_callback(
+                        host.shape, sharding, lambda idx: host[idx])
+                return jax.tree.map(one, tree)
+
+            self.shards = jax.tree.map(put, self.shards.axes(worker, whole),
+                                       self.shards)
+            self.cache = put(worker, self.cache)
+        self._placed_on = ("mesh", mesh)
+
+    def replicate(self, tree):
+        """``tree`` (params, optimizer state) committed to every device of
+        the mesh the data was placed on, so a jitted step is called with
+        the sharding its previous call returned and compiles once.
+        Returned unchanged without a mesh, or across processes (where
+        JAX replicates the host arrays itself)."""
+        mesh = self._placed_on[1] if self._placed_on else None
+        if mesh is None or jax.process_count() > 1:
+            return tree
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        return jax.device_put(tree, NamedSharding(mesh, P()))
 
     def seeds_host(self, batch: int, epoch_salt: int) -> np.ndarray:
         """Host-side half of ``seeds``: the hash-rank argsort over labeled

@@ -472,14 +472,15 @@ class FeatureStager(SeedStager):
     def _put_rows(self, rows_np: np.ndarray):
         """Transfer the staged rows, zero-copy where the backend allows.
 
-        On a single-device (CPU) backend a dlpack import hands the
-        pooled buffer over without copying (~half the staging cost at
-        wide D); the pool distance plus the driver's per-step sync (the
+        On an unsharded CPU backend a dlpack import hands the pooled
+        buffer over without copying (~half the staging cost at wide D);
+        the pool distance plus the driver's per-step sync (the
         ``recycles_buffers`` contract) guarantee the aliased buffer is
-        not rewritten while the device still reads it.  Sharded /
-        multi-host placements fall back
-        to the ``_put`` transfer paths."""
-        if self.sharding is None:
+        not rewritten while the device still reads it.  Anywhere else —
+        an accelerator, where the import would yield a CPU-device array,
+        or a sharded / multi-host placement — the rows take the ``_put``
+        transfer paths."""
+        if self.sharding is None and jax.default_backend() == "cpu":
             try:
                 return jax.dlpack.from_dlpack(rows_np)
             except Exception:       # non-importable layout: copy instead

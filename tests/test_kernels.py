@@ -217,3 +217,57 @@ def test_fused_level_overflow_sink():
                        window=window)
     assert len(sink) == 1 and int(sink[0]) > 0
     assert fused_sample_level.supports_overflow_sink
+
+
+# ---------------------------------------------------------------------------
+# interpret mode follows the backend
+# ---------------------------------------------------------------------------
+
+def test_interpret_follows_backend(monkeypatch):
+    """Kernels interpret exactly when the default backend is the CPU,
+    decided when called (at trace time), with no environment override;
+    the feature stores' "auto" policies follow the same rule."""
+    from repro import kernels
+    from repro.core.feature_store import PinnedHotStore, StagedStore
+
+    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "0")   # consulted by nothing
+    assert jax.default_backend() == "cpu"
+    assert kernels.resolve_interpret() is True
+    assert kernels.resolve_interpret(False) is False
+    assert not StagedStore().hot_rows_from_cache
+    table = jnp.arange(12.0).reshape(4, 3)
+    ids = jnp.array([1, -1, 3], jnp.int32)
+    np.testing.assert_array_equal(
+        np.asarray(PinnedHotStore()._gather_hits(table, ids)),
+        np.asarray(gather_rows_reference(table, ids)))
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kernels.resolve_interpret() is False
+    assert kernels.resolve_interpret(True) is True
+    assert StagedStore().hot_rows_from_cache
+
+
+def test_kernels_match_oracles_under_tpu_dma_semantics():
+    """The TPU interpreter runs each DMA as late as a wait allows and out
+    of order: several copies signalling one semaphore only say that
+    *some* of them landed.  Both kernels must still match their oracles
+    (the fused sampler with window truncation and overflow)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    params = pltpu.InterpretParams(dma_execution_mode="on_wait")
+    g = make_power_law_graph(400, 8, num_features=4, num_classes=3,
+                             seed=2).graph
+    seeds = jnp.arange(-1, 39, dtype=jnp.int32)
+    s_k, r_k, ovf = fused_sample(g.indptr, g.indices, seeds, jnp.uint32(1),
+                                 fanout=3, window=4, interpret=params)
+    s_r, r_r, ovf_r = ref_windowed_fused_sample(g, seeds, 3, 1, 4)
+    np.testing.assert_array_equal(np.asarray(s_k), np.asarray(s_r))
+    np.testing.assert_array_equal(np.asarray(r_k), np.asarray(r_r))
+    assert int(ovf) == ovf_r > 0
+
+    table = jnp.asarray(np.random.default_rng(5).normal(size=(50, 8)),
+                        jnp.float32)
+    ids = jnp.arange(-2, 60, dtype=jnp.int32)       # -1s and out of range
+    np.testing.assert_array_equal(
+        np.asarray(gather_rows(table, ids, block=16, interpret=params)),
+        np.asarray(gather_rows_reference(table, ids)))

@@ -59,6 +59,8 @@ def test_rank_env_wiring():
         == 1
     assert "--xla_dump_to=/tmp/x" in env["XLA_FLAGS"]
     assert env["PATH"] == "/usr/bin"
+    # the fleet is a CPU simulation: ranks never reach for a chip
+    assert env["JAX_PLATFORMS"] == "cpu"
     assert base == {"XLA_FLAGS": "--xla_force_host_platform_device_count=8 "
                                  "--xla_dump_to=/tmp/x",
                     "PATH": "/usr/bin"}     # input not mutated

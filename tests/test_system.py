@@ -85,3 +85,29 @@ def test_serve_subprocess(subproc):
         "repro.launch.serve_lm", "--arch", "stablelm-1.6b", "--reduced",
         "--batch", "2", "--prompt-len", "16", "--gen", "8",
         expect="decoded 8 tokens")
+
+
+def test_compile_cache_location(monkeypatch):
+    """The entry points' compile cache: JAX_COMPILATION_CACHE_DIR when
+    set (left to JAX), else one fixed git-ignored directory inside the
+    checkout."""
+    import os
+
+    import jax
+
+    from repro.launch import cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = cache.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(repo, ".jax_cache")
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
