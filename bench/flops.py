@@ -1,0 +1,49 @@
+"""Work counts of one training step, from the configuration's shapes
+alone, so the count is the same whatever implements the step.
+
+Block sizes are nominal: the top level has ``batch`` destination nodes,
+and each level's sources are its destinations times ``1 + fanout``
+(the destinations themselves and one slot per draw).  For products at
+batch 1,000 and fanouts 15/10/5 the destination sets are 1,000, 16,000
+and 176,000 nodes over 16,000, 176,000 and 1,056,000 sources.
+"""
+from __future__ import annotations
+
+
+def block_sizes(batch: int, fanouts) -> list[int]:
+    """Frontier sizes, top first: ``batch`` and each level's sources."""
+    sizes = [int(batch)]
+    for f in fanouts:
+        sizes.append(sizes[-1] * (1 + int(f)))
+    return sizes
+
+
+def sage_forward_flops(batch: int, fanouts, dims) -> int:
+    """Forward FLOPs of GraphSAGE: per layer, the two (N_dst x D_in) @
+    (D_in x D_out) products (self and neighbour, 2 FLOPs a
+    multiply-add) and the neighbour sum (one add per drawn row entry).
+    Layer ``l`` (bottom first) consumes the MFG at depth ``L - 1 - l``."""
+    sizes = block_sizes(batch, fanouts)
+    depth = len(fanouts)
+    total = 0
+    for layer in range(depth):
+        d = depth - 1 - layer
+        n_dst, f = sizes[d], int(fanouts[d])
+        d_in, d_out = int(dims[layer]), int(dims[layer + 1])
+        total += 2 * 2 * n_dst * d_in * d_out + n_dst * f * d_in
+    return total
+
+
+def sage_step_flops(batch: int, fanouts, dims) -> int:
+    """Forward plus backward (counted as twice the forward) FLOPs of one
+    step; recomputation is not counted."""
+    return 3 * sage_forward_flops(batch, fanouts, dims)
+
+
+def fused_sample_bytes(batch: int, fanouts) -> int:
+    """HBM bytes the fused sampler must move in one step, summed over
+    levels: per seed its two ``indptr`` words, ``F`` drawn ids read and
+    ``F`` written (int32), and its row count."""
+    sizes = block_sizes(batch, fanouts)
+    return sum(sizes[d] * (2 * 4 + 2 * 4 * int(f) + 4)
+               for d, f in enumerate(fanouts))
