@@ -55,8 +55,7 @@ def _overhead_arm(layout, cfg, loss_fn, ds_cols, P, batch, steps):
     dt = {}
     for traced in (False, True):
         if traced:
-            obs_trace.start(OVERHEAD_TRACE, fenced=False,
-                            process_name="bench_obs")
+            obs_trace.start(OVERHEAD_TRACE, process_name="bench_obs")
         try:
             with pipe.train_driver(loss_fn, batch=batch,
                                    lr=6e-3) as driver:
@@ -104,7 +103,7 @@ def run(ds, P=4, batch=128, steps=6):
 
     # one trace, all schemes: the report CLI groups the fenced profile
     # spans by their "arm" tag into the Figure-1 share table
-    obs_trace.start(TRACE_PATH, fenced=True, process_name="bench_obs")
+    obs_trace.start(TRACE_PATH, process_name="bench_obs")
     try:
         params = init_gnn_params(jax.random.key(0), cfg)
         for scheme in SCHEMES:

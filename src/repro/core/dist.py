@@ -460,18 +460,19 @@ def vanilla_sample(shard: WorkerShard, offsets: jnp.ndarray,
     mfgs = []
     frontier = seeds
     for depth, fanout in enumerate(fanouts):
-        fanout = int(fanout)
-        if depth == 0:
-            # top level: seeds are local labeled nodes -> no communication
-            samples = sample_neighbors_local(
-                shard.local_indptr, shard.local_indices, my_offset, n_local,
-                frontier, fanout, level_salt(salt, depth))
-        else:
-            samples, level_bytes = exchange_sample_level(
-                shard, offsets, num_parts, frontier, fanout,
-                level_salt(salt, depth), counter)
-            util = util + level_bytes
-        mfg = finish_level(frontier, samples, fused)
+        with jax.named_scope(f"level{depth}"):
+            fanout = int(fanout)
+            if depth == 0:
+                # top level: seeds are local labeled nodes -> no communication
+                samples = sample_neighbors_local(
+                    shard.local_indptr, shard.local_indices, my_offset,
+                    n_local, frontier, fanout, level_salt(salt, depth))
+            else:
+                samples, level_bytes = exchange_sample_level(
+                    shard, offsets, num_parts, frontier, fanout,
+                    level_salt(salt, depth), counter)
+                util = util + level_bytes
+            mfg = finish_level(frontier, samples, fused)
         mfgs.append(mfg)
         frontier = mfg.src_nodes
     if with_stats:

@@ -49,6 +49,7 @@ import dataclasses
 import re
 from typing import Callable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -451,33 +452,34 @@ class HybridPartialScheme(PlacementScheme):
         mfgs: list[MFG] = []
         frontier = seeds
         for depth, fanout in enumerate(fanouts):
-            fanout = int(fanout)
-            if depth == 0:
-                # seeds are locally-owned labeled nodes -> no communication
-                samples = dist.sample_neighbors_local(
-                    shard.local_indptr, shard.local_indices, my_offset,
-                    n_local, frontier, fanout, level_salt(salt, depth))
-            else:
-                if hot_any:
-                    is_hot = (hot_mask[jnp.clip(frontier, 0)]
-                              & (frontier >= 0))
-                    hot_frontier = jnp.where(is_hot, frontier, -1)
-                    hot_samples, _ = sample_neighbors(
-                        hot_graph, hot_frontier, fanout,
-                        level_salt(salt, depth))
-                if complete:
-                    samples = hot_samples
+            with jax.named_scope(f"level{depth}"):
+                fanout = int(fanout)
+                if depth == 0:
+                    # seeds are locally-owned labeled nodes -> no communication
+                    samples = dist.sample_neighbors_local(
+                        shard.local_indptr, shard.local_indices, my_offset,
+                        n_local, frontier, fanout, level_salt(salt, depth))
                 else:
-                    cold_frontier = (jnp.where(is_hot, -1, frontier)
-                                     if hot_any else frontier)
-                    cold_samples, level_bytes = dist.exchange_sample_level(
-                        shard, offsets, P, cold_frontier, fanout,
-                        level_salt(salt, depth), counter)
-                    samples = (jnp.where(is_hot[:, None], hot_samples,
-                                         cold_samples)
-                               if hot_any else cold_samples)
-                    util = util + level_bytes
-            mfg = dist.finish_level(frontier, samples, fused)
+                    if hot_any:
+                        is_hot = (hot_mask[jnp.clip(frontier, 0)]
+                                  & (frontier >= 0))
+                        hot_frontier = jnp.where(is_hot, frontier, -1)
+                        hot_samples, _ = sample_neighbors(
+                            hot_graph, hot_frontier, fanout,
+                            level_salt(salt, depth))
+                    if complete:
+                        samples = hot_samples
+                    else:
+                        cold_frontier = (jnp.where(is_hot, -1, frontier)
+                                         if hot_any else frontier)
+                        cold_samples, level_bytes = dist.exchange_sample_level(
+                            shard, offsets, P, cold_frontier, fanout,
+                            level_salt(salt, depth), counter)
+                        samples = (jnp.where(is_hot[:, None], hot_samples,
+                                             cold_samples)
+                                   if hot_any else cold_samples)
+                        util = util + level_bytes
+                mfg = dist.finish_level(frontier, samples, fused)
             mfgs.append(mfg)
             frontier = mfg.src_nodes
         return mfgs, util

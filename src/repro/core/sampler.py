@@ -87,48 +87,50 @@ def relabel(seeds: jnp.ndarray, samples: jnp.ndarray, valid: jnp.ndarray):
     Returns (edges_local (S,F) int32, src_nodes (S + S*F,) int32 padded -1,
              num_src ()).  src_nodes[:S] == seeds.
     """
-    S = seeds.shape[0]
-    cap = samples.size
+    with jax.named_scope("relabel"):
+        S = seeds.shape[0]
+        cap = samples.size
 
-    seed_ok = seeds >= 0
-    seeds_key = jnp.where(seed_ok, seeds, _SENTINEL)
-    seed_order = jnp.argsort(seeds_key)
-    seeds_sorted = seeds_key[seed_order]
+        seed_ok = seeds >= 0
+        seeds_key = jnp.where(seed_ok, seeds, _SENTINEL)
+        seed_order = jnp.argsort(seeds_key)
+        seeds_sorted = seeds_key[seed_order]
 
-    flat = samples.reshape(-1)
-    flat_valid = valid.reshape(-1)
+        flat = samples.reshape(-1)
+        flat_valid = valid.reshape(-1)
 
-    # membership of each sample in the seed set
-    pos = jnp.searchsorted(seeds_sorted, flat)
-    pos_c = jnp.clip(pos, 0, S - 1)
-    is_seed = (seeds_sorted[pos_c] == flat) & flat_valid
-    seed_local = seed_order[pos_c]
+        # membership of each sample in the seed set
+        pos = jnp.searchsorted(seeds_sorted, flat)
+        pos_c = jnp.clip(pos, 0, S - 1)
+        is_seed = (seeds_sorted[pos_c] == flat) & flat_valid
+        seed_local = seed_order[pos_c]
 
-    # unique over non-seed samples
-    nonseed = jnp.where(flat_valid & ~is_seed, flat, _SENTINEL)
-    ns_sorted = jnp.sort(nonseed)
-    first = jnp.concatenate([jnp.array([True]),
-                             ns_sorted[1:] != ns_sorted[:-1]])
-    is_new = first & (ns_sorted != _SENTINEL)
-    rank = jnp.cumsum(is_new) - 1                     # rank among new nodes
-    num_new = jnp.sum(is_new).astype(jnp.int32)
+        # unique over non-seed samples
+        nonseed = jnp.where(flat_valid & ~is_seed, flat, _SENTINEL)
+        ns_sorted = jnp.sort(nonseed)
+        first = jnp.concatenate([jnp.array([True]),
+                                 ns_sorted[1:] != ns_sorted[:-1]])
+        is_new = first & (ns_sorted != _SENTINEL)
+        rank = jnp.cumsum(is_new) - 1                 # rank among new nodes
+        num_new = jnp.sum(is_new).astype(jnp.int32)
 
-    # compact the unique new nodes (sorted ascending), pad with sentinel
-    new_nodes = jnp.full((cap,), _SENTINEL, jnp.int32)
-    scatter_to = jnp.where(is_new, rank, cap)         # cap = dropped
-    new_nodes = new_nodes.at[scatter_to].set(ns_sorted, mode="drop")
+        # compact the unique new nodes (sorted ascending), pad with sentinel
+        new_nodes = jnp.full((cap,), _SENTINEL, jnp.int32)
+        scatter_to = jnp.where(is_new, rank, cap)         # cap = dropped
+        new_nodes = new_nodes.at[scatter_to].set(ns_sorted, mode="drop")
 
-    # local id of each non-seed sample = S + its rank among unique new nodes
-    ns_rank = jnp.searchsorted(new_nodes, flat)
-    local = jnp.where(is_seed, seed_local, S + ns_rank).astype(jnp.int32)
-    local = jnp.where(flat_valid, local, -1)
+        # local id of each non-seed sample = S + its rank among unique new
+        # nodes
+        ns_rank = jnp.searchsorted(new_nodes, flat)
+        local = jnp.where(is_seed, seed_local, S + ns_rank).astype(jnp.int32)
+        local = jnp.where(flat_valid, local, -1)
 
-    src_nodes = jnp.concatenate([
-        jnp.where(seed_ok, seeds, -1),
-        jnp.where(new_nodes == _SENTINEL, -1, new_nodes),
-    ])
-    num_src = S + num_new
-    return local.reshape(samples.shape), src_nodes, num_src
+        src_nodes = jnp.concatenate([
+            jnp.where(seed_ok, seeds, -1),
+            jnp.where(new_nodes == _SENTINEL, -1, new_nodes),
+        ])
+        num_src = S + num_new
+        return local.reshape(samples.shape), src_nodes, num_src
 
 
 def build_indptr(valid: jnp.ndarray) -> jnp.ndarray:
@@ -263,8 +265,9 @@ def sample_mfgs(graph: CSCGraph, seeds: jnp.ndarray,
     mfgs = []
     frontier = seeds
     for depth, fanout in enumerate(fanouts):
-        mfg = level_fn(graph, frontier, int(fanout),
-                       level_salt(salt, depth))
+        with jax.named_scope(f"level{depth}"):
+            mfg = level_fn(graph, frontier, int(fanout),
+                           level_salt(salt, depth))
         mfgs.append(mfg)
         frontier = mfg.src_nodes
     return mfgs

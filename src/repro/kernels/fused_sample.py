@@ -190,6 +190,7 @@ def fused_sample(indptr: jnp.ndarray, indices: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=resolve_interpret(interpret),
+        name="fused_sample",
     )(salt_arr, seeds_p, start, deg, indices_2d)
     samples = samples.reshape(s_pad, fanout)[:S]
     r = jnp.concatenate([jnp.zeros((1,), jnp.int32), r_incl[:S]])

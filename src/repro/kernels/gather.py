@@ -144,4 +144,5 @@ def gather_rows(table: jnp.ndarray, ids: jnp.ndarray, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=resolve_interpret(interpret),
+        name="gather_rows",
     )(ids_p, table_p)[:N]

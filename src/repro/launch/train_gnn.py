@@ -108,11 +108,6 @@ def main():
                          "and the parent merges them into OUT.json "
                          "(rank-as-pid).  Render the span summary with "
                          "'python -m repro.obs.report OUT.json --summary'")
-    ap.add_argument("--trace-fence", action="store_true",
-                    help="block_until_ready inside traced spans: honest "
-                         "device-time attribution per span, at the cost "
-                         "of destroying the prepare/consume overlap — a "
-                         "profiling mode, never for production numbers")
     args = ap.parse_args()
 
     executor = args.executor or ("shard_map" if args.shard_map else "vmap")
@@ -176,7 +171,7 @@ def main():
         # merges the rank files after the fleet exits
         path = args.trace if executor != "multiprocess" \
             else multihost.rank_trace_path(args.trace, rank)
-        obs_trace.start(path, fenced=args.trace_fence, pid=rank,
+        obs_trace.start(path, pid=rank,
                         process_name=f"rank{rank}" if executor
                         == "multiprocess" else "train_gnn")
 

@@ -128,8 +128,10 @@ def gnn_forward(params, mfgs: Sequence[MFG], h0: jnp.ndarray,
         dk = None
         if dropout_key is not None:
             dk = jax.random.fold_in(dropout_key, l)
-        h = apply_layer(params[l], mfg, h, cfg,
-                        is_last=(l == cfg.num_layers - 1), dropout_key=dk)
+        with jax.named_scope(f"layer{l}"):
+            h = apply_layer(params[l], mfg, h, cfg,
+                            is_last=(l == cfg.num_layers - 1),
+                            dropout_key=dk)
     return h
 
 

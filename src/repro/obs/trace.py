@@ -4,9 +4,10 @@ Design constraints, in order:
 
 1. **Cheap when off.**  Every instrumentation point in the pipeline
    (driver steps, stager produces, serve flushes) calls ``span(...)``
-   unconditionally; with no tracer installed that is one global load and
-   the shared no-op context manager — no allocation, no branching in
-   callers.
+   unconditionally; with no tracer installed and no profiler session
+   collecting, that is one global load, one check of the profiler's
+   enabled state and the shared no-op context manager — no allocation,
+   no branching in callers.
 2. **Cheap when on.**  A recording span is two ``perf_counter_ns`` reads
    and one tuple stored into a **preallocated ring** under a lock (spans
    are emitted a handful of times per training step, never per edge).
@@ -25,25 +26,36 @@ microsecond timestamps relative to the tracer's start, plus
 per-rank trace files into one fleet trace by mapping rank -> ``pid``
 (used by ``repro.launch.multihost``).
 
-Fencing: spans around jitted calls measure *dispatch* by default — JAX
-returns before the device finishes, which preserves the overlap the
-pipeline works hard to create.  ``start(..., fenced=True)`` opts into
-``block_until_ready`` fencing (drivers call ``fence(x)`` inside their
-spans): device time is then honestly attributed to the enclosing span,
-at the cost of destroying prepare/consume overlap — a profiling mode,
-never a production default.
+On the device clock: while a JAX profiler session is collecting
+(``jax.profiler.trace`` / ``start_trace``, or a remote capture), every
+span also enters ``jax.profiler.TraceAnnotation(name, **args)``, so the
+same instrumentation points land in the profile's ``.xplane.pb`` on the
+clock the device's ``XLA Ops`` use — with or without a tracer
+installed.  Spans around jitted calls time *dispatch* (JAX returns before
+the device finishes); the device trace shows where the device time went,
+and the idle stretches under each host span.
+
+What the program notes of itself while a session collects outlives the
+objects that noted it, for whoever reads the session's trace afterwards
+(``profiled()``): each span's seconds and count, the metrics of each
+driver step, and the map from the trace's op names to the program's
+named scopes that each driver publishes when it closes.
 """
 from __future__ import annotations
 
+import collections
 import json
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 _NS_PER_US = 1000.0
 
 
 class _NullSpan:
-    """Shared no-op context manager returned when tracing is off."""
+    """Shared no-op context manager returned when tracing is off and no
+    profiler session is collecting."""
 
     __slots__ = ()
 
@@ -57,10 +69,89 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """One recording span: times itself between __enter__ and __exit__."""
+# collecting(): whether a JAX profiler session collects host annotations
+collecting = TraceAnnotation.is_enabled
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+
+class Profiled:
+    """What the program noted of itself while JAX profiler sessions
+    collected, since the process started or the last ``clear``.
+
+    * ``spans``: ``{name: [seconds, count]}`` of the spans that closed
+      while a session collected (host time, ``perf_counter_ns``);
+    * ``steps``: the metrics dict of each driver step taken while a
+      session collected, the last ``STEPS_KEPT`` (device values, read
+      when wanted);
+    * ``scopes``: ``{HLO instruction name: op_name}`` of the top-level
+      instructions of the programs those steps ran
+      (``repro.obs.scopes``), published by each driver as it closes; a
+      name two programs give different scopes is left out.
+    """
+
+    STEPS_KEPT = 4096
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.clear()
+
+    def clear(self) -> None:
+        with self._lock:
+            self.spans: dict[str, list] = {}
+            self.steps = collections.deque(maxlen=self.STEPS_KEPT)
+            self.scopes: dict[str, str] = {}
+
+    def add_span(self, name: str, dur_ns: int) -> None:
+        with self._lock:
+            entry = self.spans.setdefault(name, [0.0, 0])
+            entry[0] += dur_ns * 1e-9
+            entry[1] += 1
+
+    def add_step(self, metrics: dict) -> None:
+        self.steps.append(metrics)
+
+    def add_scopes(self, scopes: dict[str, str]) -> None:
+        from repro.obs.scopes import merge
+
+        with self._lock:
+            self.scopes = merge([self.scopes, scopes])
+
+
+_PROFILED = Profiled()
+
+
+def profiled() -> Profiled:
+    """The process's record of what the program noted while profiler
+    sessions collected (``Profiled``)."""
+    return _PROFILED
+
+
+class _Annotated:
+    """A span with no tracer installed while a profiler session
+    collects: a ``TraceAnnotation`` whose time is also totalled in
+    ``profiled().spans``."""
+
+    __slots__ = ("_name", "_ann", "_t0")
+
+    def __init__(self, name, args):
+        self._name = name
+        self._ann = TraceAnnotation(name, **args)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        _PROFILED.add_span(self._name, time.perf_counter_ns() - self._t0)
+        self._ann.__exit__(*exc)
+        return False
+
+
+class _Span:
+    """One recording span: times itself between __enter__ and __exit__,
+    and annotates the profiler trace while a session is collecting."""
+
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_ann")
 
     def __init__(self, tracer, name, cat, args):
         self._tracer = tracer
@@ -69,6 +160,10 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        self._ann = None
+        if collecting():
+            self._ann = TraceAnnotation(self._name, **(self._args or {}))
+            self._ann.__enter__()
         self._tracer._stack().append(self)
         self._t0 = time.perf_counter_ns()
         return self
@@ -80,6 +175,9 @@ class _Span:
             stack.pop()
         self._tracer._record(self._name, self._cat, self._t0, dur,
                              self._args)
+        if self._ann is not None:
+            _PROFILED.add_span(self._name, dur)
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -92,10 +190,6 @@ class Tracer:
         Ring slots.  When full, the oldest events are overwritten and
         counted in ``dropped`` (surfaced in the exported trace's
         metadata) — recording never reallocates or blocks.
-    fenced : bool, default False
-        Advertise ``block_until_ready`` fencing to instrumentation
-        points (see module docstring).  The tracer itself never blocks;
-        callers consult ``fenced`` via ``repro.obs.trace.fenced()``.
     pid : int, default 0
         Process id stamped on events (multi-host ranks export with
         ``pid=rank``; ``merge_traces`` can also remap afterwards).
@@ -111,12 +205,11 @@ class Tracer:
     1
     """
 
-    def __init__(self, capacity: int = 65536, *, fenced: bool = False,
-                 pid: int = 0, process_name: str | None = None):
+    def __init__(self, capacity: int = 65536, *, pid: int = 0,
+                 process_name: str | None = None):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = int(capacity)
-        self.fenced = bool(fenced)
         self.pid = int(pid)
         self.process_name = process_name
         self.t_origin_ns = time.perf_counter_ns()
@@ -154,11 +247,6 @@ class Tracer:
             self._ring[self._count % self.capacity] = (
                 name, cat, tid, t0_ns, dur_ns, args)
             self._count += 1
-
-    def instant(self, name: str, cat: str | None = None, **args) -> None:
-        """Record a zero-duration marker at the current time."""
-        t = time.perf_counter_ns()
-        self._record(name, cat, t, 0, args or None)
 
     def event(self, name: str, ts_s: float, dur_s: float, *,
               tid: int = 0, pid: int | None = None,
@@ -257,8 +345,7 @@ _ACTIVE: Tracer | None = None
 
 
 def start(path: str | None = None, *, capacity: int = 65536,
-          fenced: bool = False, pid: int = 0,
-          process_name: str | None = None) -> Tracer:
+          pid: int = 0, process_name: str | None = None) -> Tracer:
     """Install (and return) a fresh global tracer.
 
     ``path`` is remembered so ``stop()`` exports there; pass ``None`` to
@@ -266,8 +353,7 @@ def start(path: str | None = None, *, capacity: int = 65536,
     it (the old one keeps its recorded spans but receives no new ones).
     """
     global _ACTIVE
-    tracer = Tracer(capacity, fenced=fenced, pid=pid,
-                    process_name=process_name)
+    tracer = Tracer(capacity, pid=pid, process_name=process_name)
     tracer._export_path = path
     _ACTIVE = tracer
     return tracer
@@ -291,42 +377,19 @@ def active_tracer() -> Tracer | None:
 
 
 def span(name: str, cat: str | None = None, **args):
-    """Span on the installed tracer; the shared no-op when tracing is
-    off.  This is the form instrumentation points use:
+    """Span on the installed tracer, annotating the profiler trace while
+    a session collects; the shared no-op when neither is on.  This is the
+    form instrumentation points use:
 
     >>> with span("driver/step", cat="driver", step=3):
     ...     pass
     """
     t = _ACTIVE
-    if t is None:
-        return _NULL_SPAN
-    return t.span(name, cat, **args)
-
-
-def instant(name: str, cat: str | None = None, **args) -> None:
-    """Instant marker on the installed tracer (no-op when off)."""
-    t = _ACTIVE
     if t is not None:
-        t.instant(name, cat, **args)
-
-
-def fenced() -> bool:
-    """True when an installed tracer asked for ``block_until_ready``
-    fencing (honest device-time attribution; overlap-destroying)."""
-    t = _ACTIVE
-    return t is not None and t.fenced
-
-
-def fence(x):
-    """``jax.block_until_ready(x)`` when fencing is on; ``x`` otherwise.
-
-    Called *inside* a span so the device time it exposes lands on that
-    span.  Off (the default) the call is a no-op and spans measure
-    dispatch, preserving overlap."""
-    if fenced():
-        import jax
-        jax.block_until_ready(x)
-    return x
+        return t.span(name, cat, **args)
+    if collecting():
+        return _Annotated(name, args)
+    return _NULL_SPAN
 
 
 # --------------------------------------------------------------------------

@@ -50,6 +50,7 @@ import jax
 
 from repro.core import dist
 from repro.obs import trace as _trace
+from repro.obs.scopes import Recorded
 
 
 _EXECUTORS: dict[str, Callable] = {}
@@ -122,11 +123,15 @@ class _AsyncDispatchRunner:
     passes through a jitted program is copied into a fresh output buffer
     at the boundary; attaching outside means the stager's buffer enters
     exactly one program (the consume, which fetches from it) as a
-    zero-copy input."""
+    zero-copy input.
 
-    def __init__(self, prepare_j, consume_j):
+    ``programs`` lists the jitted programs each step runs
+    (``repro.obs.scopes.Recorded``)."""
+
+    def __init__(self, prepare_j, consume_j, programs):
         self._prep = prepare_j
         self._cons = consume_j
+        self.programs = tuple(programs)
 
     @staticmethod
     def _attach(batch, rows):
@@ -138,23 +143,19 @@ class _AsyncDispatchRunner:
         """Dispatch one prepare (used by the driver to fill the queue)."""
         with _trace.span("prefetch/prepare", cat="prefetch"):
             nxt = self._attach(self._prep(seeds, salt), rows)
-            _trace.fence(nxt)
         return nxt
 
     def step(self, params, opt_state, queue, seeds, salt, rows=None):
-        # unfenced, these spans time *dispatch* — prepare(k+depth) and
-        # consume(k) still overlap on the device.  A fenced tracer
-        # (trace.start(fenced=True)) blocks inside each span for honest
-        # per-half device attribution, destroying exactly that overlap.
+        # these spans time *dispatch* — prepare(k+depth) and consume(k)
+        # still overlap on the device (the profiler trace shows each
+        # stage's device time)
         with _trace.span("prefetch/prepare", cat="prefetch"):
             nxt = self._attach(self._prep(seeds, salt), rows)  # async ...
-            _trace.fence(nxt)
         with _trace.span("prefetch/consume", cat="prefetch"):
+            # ... and only the caller blocks on device values
             params, opt_state, loss, metrics = self._cons(params,
                                                           opt_state,
                                                           queue[0])
-            # ... and only now does anyone block on device values
-            _trace.fence(loss)
         return params, opt_state, loss, metrics, queue[1:] + (nxt,)
 
 
@@ -164,11 +165,15 @@ class _RotatingBufferRunner:
     XLA rotates the prepared-batch double buffers in place.
     ``seeds_next`` may arrive pre-staged and pre-sharded along the worker
     axis (``ShardMapExecutor.seed_sharding``), in which case the fused
-    program starts from already-resident per-device rows."""
+    program starts from already-resident per-device rows.
 
-    def __init__(self, warm_j, fused_j):
+    ``programs`` lists the jitted programs each step runs (the fused one;
+    the warm-up prepare runs only to fill the queue)."""
+
+    def __init__(self, warm_j, fused_j, programs):
         self._warm = warm_j
         self._fused = fused_j
+        self.programs = tuple(programs)
 
     def prepare(self, *extras):
         """Warmup-only prepare (separate jit; its trace does not tick the
@@ -309,11 +314,13 @@ class VmapExecutor:
                          axis_name=dist.AXIS)
         data = (pipeline.shards, pipeline.cache)
 
+        @Recorded
         @jax.jit
         def prepare_raw(seeds, salt, data):
             shards, cache = data
             return vprep(shards, seeds, salt, cache)
 
+        @Recorded
         @jax.jit
         def consume_raw(params, opt_state, batch, data):
             shards, cache = data
@@ -326,7 +333,8 @@ class VmapExecutor:
         return _AsyncDispatchRunner(
             lambda seeds, salt: prepare_raw(seeds, salt, data),
             lambda params, opt_state, batch: consume_raw(
-                params, opt_state, batch, data))
+                params, opt_state, batch, data),
+            (prepare_raw, consume_raw))
 
 
 class ShardMapExecutor:
@@ -458,6 +466,7 @@ class ShardMapExecutor:
             pipeline, prepare, prepare_warm, consume)
         data = (pipeline.shards, pipeline.cache)
 
+        @Recorded
         @partial(jax.jit, donate_argnums=(2,))
         def fused_raw(params, opt_state, queue, seeds_next, salt_next,
                       rest, data):
@@ -480,7 +489,7 @@ class ShardMapExecutor:
             return fused_raw(params, opt_state, queue, seeds_next,
                              salt_next, tuple(rest), data)
 
-        return _RotatingBufferRunner(warm_j, fused_j)
+        return _RotatingBufferRunner(warm_j, fused_j, (fused_raw,))
 
     def _build_prefetch_smaps(self, pipeline, prepare, prepare_warm,
                               consume):

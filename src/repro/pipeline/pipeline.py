@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.core import dist
 from repro.core.graph import CSCGraph
+from repro.obs.scopes import Recorded
 from repro.pipeline import worker as _worker
 from repro.pipeline.executor import resolve_executor
 from repro.pipeline.specs import PipelineSpec
@@ -459,12 +460,14 @@ class Pipeline:
         if jit:
             # the placed data is an argument of the jitted program, never
             # a constant baked into it
-            step = jax.jit(step)
+            step = Recorded(jax.jit(step))
 
         def fn(params, opt_state, seeds, salt):
             params, opt_state = self.replicate((params, opt_state))
             return step(params, opt_state, seeds, salt, data)
 
+        # the program each call runs (repro.obs.scopes.Recorded)
+        fn.programs = (step,) if jit else ()
         return fn
 
     def train_driver(self, loss_fn, *, batch: int, lr: float = 1e-3,

@@ -50,6 +50,7 @@ from jax import lax
 
 from repro.core import dist
 from repro.core.sampler import resolve_backend
+from repro.obs import scopes as _scopes
 from repro.obs import trace as _trace
 from repro.pipeline.specs import SEED_STREAMS
 
@@ -233,9 +234,10 @@ def make_prepare_fetch_consume(*, offsets: jnp.ndarray, num_parts: int,
     row_bytes_of = lambda feats: 4.0 + feats.shape[1] * feats.dtype.itemsize
 
     def _fetch(src, shard, cache, staged=None):
-        return store.fetch(src, shard, cache, offsets=offsets,
-                           num_parts=num_parts, counter=counter,
-                           staged_rows=staged)
+        with jax.named_scope("fetch"):
+            return store.fetch(src, shard, cache, offsets=offsets,
+                               num_parts=num_parts, counter=counter,
+                               staged_rows=staged)
 
     def _feature_bytes(src, hits, shard):
         # utilized feature volume: ids out + rows back for every valid
@@ -259,10 +261,11 @@ def make_prepare_fetch_consume(*, offsets: jnp.ndarray, num_parts: int,
             def lf(graph, frontier, fanout, level_salt):
                 return level_fn(graph, frontier, fanout, level_salt,
                                 overflow_sink=sink)
-        mfgs, samp_bytes = plan.sample(shard, seeds, fanouts, salt,
-                                       level_fn=lf,
-                                       fused=vanilla_fused,
-                                       counter=counter)
+        with jax.named_scope("sample"):
+            mfgs, samp_bytes = plan.sample(shard, seeds, fanouts, salt,
+                                           level_fn=lf,
+                                           fused=vanilla_fused,
+                                           counter=counter)
         # per-level attribution: the sink receives one count per level_fn
         # call in sampling order (one per level for every scheme; any
         # extra calls land on the last level)
@@ -317,8 +320,10 @@ def make_prepare_fetch_consume(*, offsets: jnp.ndarray, num_parts: int,
         h_src, hits = batch.h_src, batch.hits
 
         def objective(p):
-            return loss_fn(p, mfgs, h_src, batch.seed_labels,
-                           batch.seed_valid)
+            # the backward pass lands under transpose(jvp(forward))
+            with jax.named_scope("forward"):
+                return loss_fn(p, mfgs, h_src, batch.seed_labels,
+                               batch.seed_valid)
 
         loss, grads = jax.value_and_grad(objective)(params)
         # order-deterministic reductions (all_gather + local reduce): the
@@ -327,7 +332,8 @@ def make_prepare_fetch_consume(*, offsets: jnp.ndarray, num_parts: int,
         # "multiprocess" — produces bit-identical loss/grads
         grads = dist.pmean_ordered(grads)
         loss = dist.pmean_ordered(loss)
-        hit_rate = hits / jnp.maximum(jnp.sum(mfgs[-1].src_nodes >= 0), 1)
+        live = jnp.stack([jnp.sum(m.src_nodes >= 0) for m in mfgs])
+        hit_rate = hits / jnp.maximum(live[-1], 1)
         metrics = {
             "cache_hit_rate": dist.pmean_ordered(
                 hit_rate.astype(jnp.float32)),
@@ -347,6 +353,12 @@ def make_prepare_fetch_consume(*, offsets: jnp.ndarray, num_parts: int,
                 comm.get("sampler_window_overflow_per_level",
                          jnp.zeros((len(fanouts),), jnp.int32)
                          ).astype(jnp.float32)),
+            # frontier slots per level (top first) holding a node after
+            # dedup, against the static slots relabel keeps, fabric-wide
+            "frontier_live": dist.psum_ordered(live.astype(jnp.float32)),
+            "frontier_slots": jnp.asarray(
+                [m.src_nodes.shape[0] * num_parts for m in mfgs],
+                jnp.float32),
         }
         return loss, grads, metrics
 
@@ -369,11 +381,12 @@ def make_update_fn(*, lr: float = 1e-3, optimizer: str = "adamw",
     from repro.optim.optimizers import clip_by_global_norm
 
     def update(params, opt_state, grads, metrics):
-        if grad_clip is not None:
-            grads, gnorm = clip_by_global_norm(grads, grad_clip)
-            metrics = dict(metrics, grad_norm=gnorm)
-        params, opt_state = apply_updates(params, grads, opt_state,
-                                          kind=optimizer, lr=lr)
+        with jax.named_scope("optimizer"):
+            if grad_clip is not None:
+                grads, gnorm = clip_by_global_norm(grads, grad_clip)
+                metrics = dict(metrics, grad_norm=gnorm)
+            params, opt_state = apply_updates(params, grads, opt_state,
+                                              kind=optimizer, lr=lr)
         return params, opt_state, metrics
 
     return update
@@ -490,6 +503,7 @@ class SyncDriver:
         # only sound with per-step materialization
         self._fence = getattr(self.stager, "recycles_buffers", False)
         self._next = 0
+        self._profiled = False
 
     def _seeds_salt(self, k: int):
         if self.stager is not None:
@@ -499,7 +513,9 @@ class SyncDriver:
     def step(self, params, opt_state, step_idx: int | None = None):
         """Run step ``step_idx`` (defaults to the next sequential index).
 
-        Returns ``(params, opt_state, loss, metrics)``.
+        Returns ``(params, opt_state, loss, metrics)``; while a profiler
+        session collects, the metrics are also noted in
+        ``repro.obs.trace.profiled()``.
         """
         k = self._next if step_idx is None else int(step_idx)
         with _trace.span("driver/step", cat="driver", step=k,
@@ -508,11 +524,20 @@ class SyncDriver:
                 seeds, salt = self._seeds_salt(k)
             with _trace.span("driver/train_step", cat="driver"):
                 out = self._fn(params, opt_state, seeds, salt)
-                _trace.fence(out)
         self._next = k + 1
+        if _trace.collecting():
+            self._profiled = True
+            _trace.profiled().add_step(out[3])
         if self._fence:
             jax.block_until_ready(out[2])
         return out
+
+    def op_scopes(self, top_level: bool = False) -> dict[str, str]:
+        """``{HLO instruction name: op_name}`` of the program each step
+        runs (``repro.obs.scopes``): what a device trace's op names mean
+        in the program's named scopes.  Empty before the first step."""
+        return _scopes.merge(p.op_scopes(top_level)
+                             for p in getattr(self._fn, "programs", ()))
 
     def reset(self) -> None:
         """Restart the sequential step counter at 0 (draining and
@@ -523,8 +548,13 @@ class SyncDriver:
 
     def close(self) -> None:
         """Release the staging thread if this driver built it (a stager
-        adopted from the caller is left running; no-op without staging).
+        adopted from the caller is left running).  A driver that stepped
+        while a profiler session collected first publishes its top-level
+        ``op_scopes`` in ``repro.obs.trace.profiled()``.
         """
+        if self._profiled:
+            _trace.profiled().add_scopes(self.op_scopes(top_level=True))
+            self._profiled = False
         if self.stager is not None and self._owns_stager:
             self.stager.close()
 
@@ -595,6 +625,7 @@ class DoubleBufferDriver:
         self._fence = getattr(self.stager, "recycles_buffers", False)
         self._queue = None
         self._next = 0
+        self._profiled = False
 
     def _seeds_salt(self, k: int):
         if self.stager is not None:
@@ -613,7 +644,8 @@ class DoubleBufferDriver:
 
         Returns ``(params, opt_state, loss, metrics)``; internally rotates
         the prepared-batch FIFO and dispatches the prepare for step
-        ``step_idx + depth``.
+        ``step_idx + depth``.  While a profiler session collects, the
+        metrics are also noted in ``repro.obs.trace.profiled()``.
         """
         k = self._next if step_idx is None else int(step_idx)
         with _trace.span("driver/step", cat="driver", step=k,
@@ -627,11 +659,21 @@ class DoubleBufferDriver:
                 params, opt_state, loss, metrics, self._queue = \
                     self._runner.step(params, opt_state, self._queue,
                                       *nxt)
-                _trace.fence(loss)
         self._next = k + 1
+        if _trace.collecting():
+            self._profiled = True
+            _trace.profiled().add_step(metrics)
         if self._fence:
             jax.block_until_ready(loss)
         return params, opt_state, loss, metrics
+
+    def op_scopes(self, top_level: bool = False) -> dict[str, str]:
+        """``{HLO instruction name: op_name}`` of the program(s) each
+        step runs (``repro.obs.scopes``); an instruction name two of them
+        give different scopes is left out.  Empty before the first
+        step."""
+        return _scopes.merge(p.op_scopes(top_level)
+                             for p in getattr(self._runner, "programs", ()))
 
     def reset(self) -> None:
         """Drop in-flight batches and restart the step counter at 0
@@ -643,8 +685,13 @@ class DoubleBufferDriver:
 
     def close(self) -> None:
         """Release the staging thread if this driver built it (a stager
-        adopted from the caller is left running; no-op without staging).
+        adopted from the caller is left running).  A driver that stepped
+        while a profiler session collected first publishes its top-level
+        ``op_scopes`` in ``repro.obs.trace.profiled()``.
         """
+        if self._profiled:
+            _trace.profiled().add_scopes(self.op_scopes(top_level=True))
+            self._profiled = False
         if self.stager is not None and self._owns_stager:
             self.stager.close()
 

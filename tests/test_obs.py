@@ -15,6 +15,7 @@ import threading
 
 import numpy as np
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.core.partition import build_layout, partition_graph
@@ -24,6 +25,7 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.profile import STAGES, profile_stages
+from repro.obs.scopes import merge, op_scopes, parts
 from repro.obs.report import (render_share_table, span_summary,
                               stage_shares)
 from repro.obs.trace import Tracer, merge_traces, validate_trace
@@ -101,11 +103,14 @@ def test_tracer_ring_wraps_and_counts_drops(tmp_path):
 
 def test_module_level_span_is_noop_when_off():
     assert obs_trace.active_tracer() is None
+    # no tracer, no profiler session: the shared no-op, nothing built
+    assert obs_trace.span("ignored", cat="driver", step=1) \
+        is obs_trace._NULL_SPAN
+    before = {k: list(v) for k, v in obs_trace.profiled().spans.items()}
     with obs_trace.span("ignored", cat="driver"):
         pass                                     # must not raise
-    assert obs_trace.fence(42) == 42             # unfenced: identity
-    t = obs_trace.start(None, fenced=True)
-    assert obs_trace.fenced()
+    assert obs_trace.profiled().spans == before  # nothing noted
+    t = obs_trace.start(None)
     with obs_trace.span("seen"):
         pass
     assert obs_trace.stop(export=False) is t
@@ -266,31 +271,159 @@ def test_driver_steps_are_traced(world, depth):
     assert not any(e.get("cat") in STAGES for e in evs)
 
 
-def test_fenced_driver_matches_unfenced_losses(world):
+def _host_events(logdir):
+    """``(name, start_ns, end_ns, stats)`` of every host event in the
+    profile written under ``logdir``."""
+    from jax.profiler import ProfileData
+
+    path = next(logdir.rglob("*.xplane.pb"))
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+             dict(ev.stats))
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+@pytest.mark.parametrize("ring", [False, True],
+                         ids=["profiler_only", "with_tracer"])
+def test_driver_spans_land_on_the_profiler_trace(world, tmp_path, ring):
+    """The program's spans are profiler annotations: ``driver/seeds``
+    nests in ``driver/step`` (which carries ``step=k``) on the trace's
+    clock, whether or not a ring tracer is installed."""
     ds, layout, cfg, params = world
     pipe = Pipeline.from_layout(layout, _spec(depth=1))
-
-    def run():
-        with pipe.train_driver(_loss_fn(cfg), batch=8, lr=0.01) as d:
-            p, opt = params, init_opt_state(params, kind="adamw")
-            out = []
-            for k in range(3):
-                p, opt, loss, _ = d.step(p, opt, k)
-                out.append(float(loss))
-            return out
-
-    base = run()
-    obs_trace.start(None, fenced=True)
-    fenced = run()
+    tracer = obs_trace.start(None) if ring else None
+    record = obs_trace.profiled()
+    record.clear()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with pipe.train_driver(_loss_fn(cfg), batch=8, lr=0.01) as driver:
+        p, opt = params, init_opt_state(params, kind="adamw")
+        p, opt, loss, _ = driver.step(p, opt, 0)       # compiles
+        assert not record.spans and not record.steps
+        with jax.profiler.trace(str(tmp_path), profiler_options=options):
+            for k in (1, 2):
+                p, opt, loss, _ = driver.step(p, opt, k)
+            jax.block_until_ready(loss)
+        assert not record.scopes                # published on close
     obs_trace.stop(export=False)
-    assert fenced == base          # fencing changes timing, not results
+    events = _host_events(tmp_path)
+    steps = [e for e in events if e[0] == "driver/step"]
+    assert sorted(e[3]["step"] for e in steps) == [1, 2]
+    assert all(e[3]["mode"] == "double_buffer" for e in steps)
+    seeds = [e for e in events if e[0] == "driver/seeds"]
+    assert len(seeds) == 2
+    for _, s, e, _ in seeds:
+        assert sum(a <= s and e <= b for _, a, b, _ in steps) == 1
+    assert {"driver/runner_step", "prefetch/prepare",
+            "prefetch/consume"} <= {e[0] for e in events}
+    if ring:
+        recorded = [e for e in tracer.events() if e.get("ph") == "X"
+                    and e["name"] == "driver/step"]
+        assert [e["args"]["step"] for e in recorded] == [0, 1, 2]
+    # what the program noted of itself while the session collected
+    assert record.spans["driver/seeds"][1] == 2
+    assert record.spans["driver/step"][1] == 2
+    spans_s = sum(e - s for name, s, e, _ in events
+                  if name == "driver/step") * 1e-9
+    assert 0 < record.spans["driver/step"][0] <= spans_s
+    assert len(record.steps) == 2
+    assert all(np.asarray(m["frontier_live"]).shape == (len(cfg.fanouts),)
+               for m in record.steps)
+    assert any("relabel" in parts(op) for op in record.scopes.values())
+    record.clear()
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_op_scopes_name_the_stages_of_the_fused_step(world, depth):
+    ds, _, cfg, params = world
+    spec = PipelineSpec.from_scheme(
+        "hybrid+fused", num_parts=1, fanouts=cfg.fanouts,
+        executor="shard_map", partitioner="random", prefetch_depth=depth)
+    pipe = Pipeline.build(ds.graph, ds.features, ds.labels, spec)
+    with pipe.train_driver(_loss_fn(cfg), batch=8, lr=0.01) as driver:
+        assert driver.op_scopes() == {}                 # nothing ran yet
+        driver.step(params, init_opt_state(params, kind="adamw"))
+        scopes = driver.op_scopes()
+        top = driver.op_scopes(top_level=True)
+    # the top-level ops (the ENTRY computation's): the relabel's while,
+    # not the ops of its body
+    assert top.items() <= scopes.items() and len(top) < len(scopes)
+    assert any(name.startswith("while") and "relabel" in parts(op)
+               for name, op in top.items())
+    assert any(name.startswith("while") and "relabel" in parts(op)
+               for name, op in scopes.items())
+    assert any("/transpose(jvp(forward))/" in op for op in scopes.values())
+    for part in ([f"level{i}" for i in range(len(cfg.fanouts))]
+                 + [f"layer{i}" for i in range(cfg.num_layers)]
+                 + ["fused_sample", "fetch", "optimizer"]):
+        assert any(part in parts(op) for op in scopes.values()), part
+    staged = [op for op in scopes.values() if parts(op)
+              & {"sample", "fetch", "forward", "optimizer"}]
+    assert len(staged) >= 0.9 * len(scopes)
+
+
+def test_frontier_counters_match_a_numpy_count(world):
+    """``frontier_live`` counts each level's distinct frontier nodes
+    (seeds and their draws) over all workers; ``frontier_slots`` the
+    slots relabel keeps for them."""
+    from repro.core.sampler import level_salt, sample_neighbors
+
+    ds, layout, cfg, params = world
+    pipe = Pipeline.from_layout(layout, _spec())
+    seeds = pipe.seeds(8, 1)
+    _, _, metrics = pipe.step_fn(_loss_fn(cfg))(params, seeds,
+                                                jnp.uint32(3))
+    live = np.zeros(len(cfg.fanouts))
+    slots = np.zeros(len(cfg.fanouts))
+    for p in range(P_):
+        frontier = np.asarray(seeds[p])
+        for depth, fanout in enumerate(cfg.fanouts):
+            samples, valid = sample_neighbors(
+                layout.graph, jnp.asarray(frontier), fanout,
+                level_salt(3, depth))
+            nodes = np.union1d(frontier[frontier >= 0],
+                               np.asarray(samples)[np.asarray(valid)])
+            live[depth] += nodes.size
+            slots[depth] += frontier.size * (fanout + 1)
+            frontier = np.full(frontier.size * (fanout + 1), -1, np.int32)
+            frontier[:nodes.size] = nodes
+    np.testing.assert_array_equal(np.asarray(metrics["frontier_live"]),
+                                  live)
+    np.testing.assert_array_equal(np.asarray(metrics["frontier_slots"]),
+                                  slots)
+    assert (live < slots).all()
+
+
+def test_op_scopes_parser_and_merge():
+    text = textwrap.dedent("""\
+        %body.7 (p.1: s32[8]) -> s32[8] {
+          ROOT %gather.3 = s32[8]{0} gather(%p.1), metadata={op_name="jit(f)/sample/level2/relabel/while/body/gather"}
+        }
+
+        ENTRY %main {
+          %p = s32[8]{0} parameter(0)
+          %while.81 = s32[8]{0} while(%p), condition=%c, body=%b, metadata={op_name="jit(f)/sample/level2/relabel/while" source_file="x.py" source_line=3}
+          ROOT %fusion.2 = f32[8]{0} fusion(%p), kind=kLoop, calls=%g, metadata={op_name="jit(f)/transpose(jvp(forward))/layer0/mul"}
+        }""")
+    scopes = op_scopes(text)
+    assert scopes == {
+        "while.81": "jit(f)/sample/level2/relabel/while",
+        "fusion.2": "jit(f)/transpose(jvp(forward))/layer0/mul",
+        "gather.3": "jit(f)/sample/level2/relabel/while/body/gather"}
+    top = op_scopes(text, top_level=True)
+    assert top == {name: scopes[name] for name in ("while.81", "fusion.2")}
+    other = {"while.81": "jit(g)/optimizer/while", "add.1": "jit(g)/add"}
+    merged = merge([scopes, other])
+    assert merged == {"fusion.2": scopes["fusion.2"], "add.1": "jit(g)/add",
+                      "gather.3": scopes["gather.3"]}
 
 
 def test_profile_stages_share_and_report_round_trip(world, tmp_path):
     ds, layout, cfg, params = world
     pipe = Pipeline.from_layout(layout, _spec())
     path = tmp_path / "stages.json"
-    obs_trace.start(str(path), fenced=True)
+    obs_trace.start(str(path))
     prof = profile_stages(pipe, _loss_fn(cfg), params, batch=8, steps=2,
                           warmup=1, arm="hybrid")
     obs_trace.stop()
@@ -437,7 +570,7 @@ def test_two_rank_fleet_merged_trace(tmp_path, subproc):
                             pid=rank, process_name=f"rank{{rank}}")
         with obs_trace.span("driver/step", cat="driver", step=0):
             out = jax.jit(lambda x: x * 2)(jnp.ones(4))
-            obs_trace.fence(out)
+            jax.block_until_ready(out)
         obs_trace.stop()
         print("rank", rank, "done")
     """)
