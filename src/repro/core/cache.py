@@ -370,7 +370,7 @@ def frequency_caches(layout: PartitionLayout, capacity: int, *,
     seed-stream training run with ``base_salt=seed`` would produce — not a
     proxy distribution.
     """
-    from repro.core.partition import seeds_per_worker
+    from repro.core.partition import labeled_ids, seeds_per_worker
     from repro.core.sampler import sample_mfgs
 
     if fanouts is None:
@@ -381,11 +381,13 @@ def frequency_caches(layout: PartitionLayout, capacity: int, *,
     P = layout.num_parts
     n = graph.num_nodes
 
+    labeled = labeled_ids(layout)
     counts = np.zeros((P, n), np.int64)
     for s in range(trace_steps):
         salt = (seed + s) % (2 ** 32)
         seeds = np.asarray(seeds_per_worker(layout, trace_batch,
-                                            epoch_salt=salt))
+                                            epoch_salt=salt,
+                                            labeled=labeled))
         for p in range(P):
             mfgs = sample_mfgs(graph, jnp.asarray(seeds[p]), fanouts,
                                jnp.uint32(salt))

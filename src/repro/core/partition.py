@@ -655,8 +655,8 @@ class PartitionLayout:
     materialized — the other rows of ``features`` are zero and must never
     be read by this rank (the global mesh places each partition's row on
     its owning process).  ``labels`` / ``node_valid`` stay full on every
-    rank: the host seed draw (``seeds_per_worker_host``) argsorts over the
-    whole labeled table.
+    rank: the host seed draw's table (``labeled_ids``) lists every
+    worker's labeled nodes.
     """
     graph: CSCGraph              # relabeled global topology
     offsets: jnp.ndarray         # (P+1,) ownership ranges
@@ -711,7 +711,8 @@ def build_layout(graph: CSCGraph, features: np.ndarray, labels: np.ndarray,
     never committed to physical memory).  Topology, offsets, labels, and
     ``node_valid`` remain full: they are small relative to features and
     every rank needs them (sampling walks the global topology; the host
-    seed draw scans the whole labeled table).
+    seed draw's table, ``labeled_ids``, lists every worker's labeled
+    nodes).
     """
     n = graph.num_nodes
     assign = np.asarray(assign)
@@ -793,50 +794,65 @@ def build_hybrid(layout: PartitionLayout) -> HybridPlan:
     return HybridPlan(layout=layout)
 
 
+def labeled_ids(layout: PartitionLayout) -> tuple[np.ndarray, ...]:
+    """The seed draw's table: per worker, the int64 global ids of its
+    labeled nodes in ascending (column) order.  Built once per layout
+    (``Pipeline`` builds it with the pipeline) and read by every
+    ``seeds_per_worker_host`` call."""
+    offsets = np.asarray(layout.offsets).astype(np.int64)
+    labels = np.asarray(layout.labels)
+    return tuple(offsets[p] + np.flatnonzero(labels[p] >= 0)
+                 for p in range(layout.num_parts))
+
+
 def seeds_per_worker_host(layout: PartitionLayout, batch: int,
-                          epoch_salt: int) -> np.ndarray:
-    """Pure-numpy host half of ``seeds_per_worker``: the hash-rank argsort
-    over all labeled nodes, returning a host ``(P, batch)`` int32 array.
+                          epoch_salt: int,
+                          labeled: tuple[np.ndarray, ...] | None = None
+                          ) -> np.ndarray:
+    """Pure-numpy host half of ``seeds_per_worker``: each worker's
+    ``batch`` labeled nodes of lowest hash rank, as a host ``(P, batch)``
+    int32 array, -1 padded.
+
+    Only the labeled ids of ``labeled`` (``labeled_ids(layout)``, built
+    here when not given) are hashed: ``np.argpartition`` keeps the lowest
+    ``min(batch, count)`` keys and only those are sorted.  ``mix64`` is a
+    bijection, so distinct ids never tie and key order alone is the order
+    by (key, column).  No unlabeled node takes part, so a labeled node
+    whose key is ``2**64 - 1`` ranks like any other (a draw that masked
+    the unlabeled nodes to that key would tie them with it).
 
     This function touches no JAX state (no tracing, no device transfer),
     so the seed stager (``repro.pipeline.staging``) can run it on a
     background thread while the main thread traces or blocks on device
     work; ``seeds_per_worker`` is its device-array wrapper.
     """
-    P = layout.num_parts
-    offsets = np.asarray(layout.offsets).astype(np.int64)
-    labels = np.asarray(layout.labels)
-    n_max = labels.shape[1]
-
-    gids = offsets[:-1, None] + np.arange(n_max, dtype=np.int64)[None, :]
+    if labeled is None:
+        labeled = labeled_ids(layout)
     # fold the salt in Python-int space (arbitrary precision, then wrap)
     salt64 = np.uint64((int(epoch_salt) * 0x9E3779B97F4A7C15) % (2 ** 64))
-    key = mix64(gids.astype(np.uint64) + salt64)
-    key = np.where(labels >= 0, key, np.uint64(np.iinfo(np.uint64).max))
-
-    m = min(batch, n_max)
-    order = np.argsort(key, axis=1, kind="stable")[:, :m]
-    picked = np.take_along_axis(gids, order, axis=1)
-    take = np.minimum((labels >= 0).sum(axis=1), m)
-    valid = np.arange(m)[None, :] < take[:, None]
-    out = np.full((P, batch), -1, np.int32)
-    out[:, :m] = np.where(valid, picked, -1)
+    out = np.full((layout.num_parts, batch), -1, np.int32)
+    for p, ids in enumerate(labeled):
+        m = min(batch, ids.size)
+        if m == 0:
+            continue
+        key = mix64(ids.astype(np.uint64) + salt64)
+        lowest = np.argpartition(key, m - 1)[:m]
+        out[p, :m] = ids[lowest[np.argsort(key[lowest])]]
     return out
 
 
-def seeds_per_worker(layout: PartitionLayout, batch: int,
-                     epoch_salt: int) -> jnp.ndarray:
+def seeds_per_worker(layout: PartitionLayout, batch: int, epoch_salt: int,
+                     labeled: tuple[np.ndarray, ...] | None = None
+                     ) -> jnp.ndarray:
     """Each worker draws its minibatch from ITS OWN labeled nodes (paper §4:
     'top level sampling seeds are drawn from the labeled nodes' of the local
     partition).  Deterministic given epoch_salt.  Returns (P, batch) global
     ids, -1 padded.
 
-    Vectorized over workers: each labeled node gets a hash rank from
-    (global id, epoch_salt) and every worker takes its ``batch``
-    lowest-ranked labeled nodes — one argsort over the (P, n_max) table
-    replaces the per-partition ``rng.choice`` loop.  Sampling without
-    replacement is preserved (distinct nodes hash to distinct ranks with
-    overwhelming probability; ties break by column order).  The host
-    argsort itself lives in ``seeds_per_worker_host``.
+    Each labeled node gets a hash rank from (global id, epoch_salt) and
+    every worker takes its ``batch`` lowest-ranked labeled nodes in rank
+    order, without replacement.  The host draw itself lives in
+    ``seeds_per_worker_host``; ``labeled`` is its prebuilt table.
     """
-    return jnp.asarray(seeds_per_worker_host(layout, batch, epoch_salt))
+    return jnp.asarray(seeds_per_worker_host(layout, batch, epoch_salt,
+                                             labeled))

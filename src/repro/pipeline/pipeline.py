@@ -39,6 +39,10 @@ class Pipeline:
     counter:           trace-time communication-round counter (sampling vs
                        feature categories); filled the first time a step
                        traces.
+    labeled:           the host seed draw's table
+                       (``repro.core.partition.labeled_ids``), built with
+                       the pipeline so every draw, the seed stager's
+                       thread included, only reads it.
     placement:         the ``PlacementPlan`` built by the spec'd scheme —
                        sampling and round accounting dispatch through it.
     dataset:           the source ``GraphDataset`` when the pipeline came
@@ -58,6 +62,7 @@ class Pipeline:
     graph_replicated: CSCGraph | None
     cache: "FeatureCache | None"                    # noqa: F821
     counter: dist.RoundCounter
+    labeled: tuple[np.ndarray, ...]
     placement: "PlacementPlan | None" = None        # noqa: F821
     dataset: "GraphDataset | None" = None           # noqa: F821
     feature_store: "FeatureStore | None" = None     # noqa: F821
@@ -192,6 +197,7 @@ class Pipeline:
         """
         from repro.core.cache import resolve_cache_policy
         from repro.core.feature_store import resolve_feature_store
+        from repro.core.partition import labeled_ids
         from repro.core.placement import resolve_scheme
 
         plan = spec.plan
@@ -237,7 +243,8 @@ class Pipeline:
         return cls(spec=spec, layout=layout, shards=shards,
                    graph_replicated=placement.replicated_graph,
                    cache=cache, counter=dist.RoundCounter(),
-                   placement=placement, feature_store=store)
+                   placement=placement, feature_store=store,
+                   labeled=labeled_ids(layout))
 
     # ------------------------------------------------------------- programs
 
@@ -585,14 +592,15 @@ class Pipeline:
         return jax.device_put(tree, NamedSharding(mesh, P()))
 
     def seeds_host(self, batch: int, epoch_salt: int) -> np.ndarray:
-        """Host-side half of ``seeds``: the hash-rank argsort over labeled
-        nodes as a pure-numpy ``(P, batch)`` int32 array.  Touches no JAX
-        tracing or device state, so the seed stager
+        """Host-side half of ``seeds``: the hash-rank draw over the
+        pipeline's ``labeled`` table as a pure-numpy ``(P, batch)`` int32
+        array.  Touches no JAX tracing or device state, so the seed stager
         (``repro.pipeline.staging``) can call it from a background thread
         while the main thread traces/executes programs."""
         from repro.core.partition import seeds_per_worker_host
         return seeds_per_worker_host(self.layout, batch,
-                                     epoch_salt=epoch_salt)
+                                     epoch_salt=epoch_salt,
+                                     labeled=self.labeled)
 
     def seeds(self, batch: int, epoch_salt: int) -> jnp.ndarray:
         """(P, batch) per-worker minibatch seeds drawn from each worker's

@@ -33,8 +33,9 @@ from the step index alone, so any prefetch depth — and any restart — replays
 the identical sample sequence, which is what makes ``depth > 0`` bit-identical
 to ``"sync"`` (asserted in ``tests/test_prefetch.py``).
 
-The remaining *host*-side serial segment — the seed argsort + its H2D
-transfer — moves off the critical path with ``PrefetchSpec(staging=True)``
+The remaining *host*-side serial segment — the seed draw (a hash rank over
+each worker's labeled ids, ``repro.core.partition.seeds_per_worker_host``)
++ its H2D transfer — moves off the critical path with ``PrefetchSpec(staging=True)``
 (or ``train_driver(staging=True)``): both drivers then consume
 already-resident device seeds from a ``repro.pipeline.staging.SeedStager``
 ring, again bit-identically (``tests/test_staging.py``).
@@ -472,7 +473,7 @@ class SyncDriver:
     ``step(params, opt_state, k)`` calls the exact jitted function
     ``Pipeline.train_step`` returns, with seeds/salt from the
     ``SeedStream`` — bit-identical to driving that function by hand.
-    With ``staging`` on, a ``SeedStager`` computes the seed argsort and
+    With ``staging`` on, a ``SeedStager`` computes the seed draw and
     starts the H2D transfer for upcoming steps on a background thread;
     the step then consumes already-resident device arrays (same values —
     the stream is a pure function of the step index).

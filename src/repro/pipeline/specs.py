@@ -185,7 +185,7 @@ class PrefetchSpec:
         is prefetched).
     staging : bool, default False
         Host-side async seed staging (``repro.pipeline.staging``): a
-        background thread computes future steps' seed argsorts and starts
+        background thread computes future steps' seed draws and starts
         their H2D transfers off the critical path, so drivers consume
         already-resident device arrays.  Composes with any depth (0
         included) and both executors; bit-identical to unstaged runs.

@@ -3,7 +3,7 @@
 PR 2's double-buffered prefetch overlapped the *device* half of minibatch
 preparation (sampling + feature ``all_to_all``) with model compute, but
 every step still blocked on host work: ``SeedStream.seeds(k)`` runs the
-hash-rank argsort over all labeled nodes on the host, and its result is
+hash-rank seed draw over the labeled nodes on the host, and its result is
 synchronously transferred to the device before the prepare can even be
 dispatched.  SALIENT ("Accelerating Training and Inference of GNNs with
 Fast Sampling and Pipelining", arXiv 2110.08450) shows this host-side
