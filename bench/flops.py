@@ -1,5 +1,7 @@
 """Work counts of one training step, from the configuration's shapes
-alone, so the count is the same whatever implements the step.
+alone, so the count is the same whatever implements the step.  A model's
+FLOPs are its module's ``step_flops`` (``bench/models/<model>.py``), at
+the nominal block sizes below.
 
 Block sizes are nominal: the top level has ``batch`` destination nodes,
 and each level's sources are its destinations times ``1 + fanout``
@@ -16,28 +18,6 @@ def block_sizes(batch: int, fanouts) -> list[int]:
     for f in fanouts:
         sizes.append(sizes[-1] * (1 + int(f)))
     return sizes
-
-
-def sage_forward_flops(batch: int, fanouts, dims) -> int:
-    """Forward FLOPs of GraphSAGE: per layer, the two (N_dst x D_in) @
-    (D_in x D_out) products (self and neighbour, 2 FLOPs a
-    multiply-add) and the neighbour sum (one add per drawn row entry).
-    Layer ``l`` (bottom first) consumes the MFG at depth ``L - 1 - l``."""
-    sizes = block_sizes(batch, fanouts)
-    depth = len(fanouts)
-    total = 0
-    for layer in range(depth):
-        d = depth - 1 - layer
-        n_dst, f = sizes[d], int(fanouts[d])
-        d_in, d_out = int(dims[layer]), int(dims[layer + 1])
-        total += 2 * 2 * n_dst * d_in * d_out + n_dst * f * d_in
-    return total
-
-
-def sage_step_flops(batch: int, fanouts, dims) -> int:
-    """Forward plus backward (counted as twice the forward) FLOPs of one
-    step; recomputation is not counted."""
-    return 3 * sage_forward_flops(batch, fanouts, dims)
 
 
 def fused_sample_bytes(batch: int, fanouts) -> int:

@@ -14,9 +14,10 @@ edges so come out in CSC order (in-neighbours of node 0, then node 1, ...)
 with no sort.  The draw depends on the graph seed and the chunk size
 alone, never on ``--seed`` or on the number of threads.
 
-The first run of a configuration writes the arrays under
-``bench/.cache/graphs/<config>-<key>/``, where the key hashes the
-configuration's graph fields; later runs load them.  Features are not
+The first run on a graph writes the arrays under
+``bench/.cache/graphs/<key>/``, where the key hashes the configuration's
+graph fields alone; later runs load them, whichever configuration on that
+graph they run.  Features are not
 cached: ``features`` draws them anew in every run, in float32.
 """
 from __future__ import annotations
@@ -127,10 +128,10 @@ def cache_key(graph: dict) -> str:
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
-def load_or_build(name: str, graph: dict, cache_dir: Path = CACHE_DIR):
+def load_or_build(graph: dict, cache_dir: Path = CACHE_DIR):
     """``(arrays, built)``: the cached arrays of ``graph``, generated and
     written first when no cache with its key exists."""
-    path = Path(cache_dir) / f"{name}-{cache_key(graph)}"
+    path = Path(cache_dir) / cache_key(graph)
     if path.is_dir():
         return {a: np.load(path / f"{a}.npy") for a in _ARRAYS}, False
     arrays = generate(graph)

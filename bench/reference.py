@@ -1,8 +1,8 @@
 """The plain reference for the benchmark's training cells, in float32.
 
 It imports nothing of the program under test.  From the same generated
-graph, features and ``--seed`` it computes what a sampled GraphSAGE
-training step has to give, by the semantics the configuration states:
+graph, features and ``--seed`` it computes what a sampled GNN training
+step has to give, by the semantics the configuration states:
 
 * seeds: step ``k`` trains the ``batch`` labelled nodes with the lowest
   SplitMix64 rank of ``(node id + (base_salt + k) * golden)``;
@@ -13,9 +13,9 @@ training step has to give, by the semantics the configuration states:
   a hub are the ones it draws from.  A node whose visible degree is at
   most the fanout takes each visible neighbour once.  The hubs met in a
   step's frontiers are counted;
-* model: 3-layer GraphSAGE (mean aggregator, ``h W_self + mean(h_nb)
-  W_neigh + b``, ReLU between layers), masked cross-entropy averaged over
-  the labelled seeds;
+* model: the weights and the loss of the configuration's model module
+  (``bench/models/<model>.py``: ``init_params`` and ``reference_loss``,
+  which puts every matrix product through ``matmul`` below);
 * update: gradients clipped to a global norm, then AdamW.
 
 The frontier of each depth is a set of node ids (sorted here), so the
@@ -126,27 +126,6 @@ def block_indices(frontiers, draws, caps):
     return out
 
 
-def init_params(seed: int, dims) -> list[dict]:
-    """GraphSAGE weights from ``seed`` in one jitted call on the default
-    device: He-normal ``w_self``/``w_neigh``, zero bias."""
-    return _init(jax.random.key(int(seed)), tuple(int(d) for d in dims))
-
-
-@functools.partial(jax.jit, static_argnums=(1,))
-def _init(key, dims):
-    params = []
-    for i, (d_in, d_out) in enumerate(zip(dims[:-1], dims[1:])):
-        k1, k2 = jax.random.split(jax.random.fold_in(key, i))
-        scale = (2.0 / d_in) ** 0.5
-        params.append({
-            "w_self": jax.random.normal(k1, (d_in, d_out), jnp.float32)
-            * scale,
-            "w_neigh": jax.random.normal(k2, (d_in, d_out), jnp.float32)
-            * scale,
-            "b": jnp.zeros((d_out,), jnp.float32)})
-    return params
-
-
 def _halves(x):
     hi = x.astype(jnp.bfloat16).astype(jnp.float32)
     return hi, (x - hi).astype(jnp.bfloat16).astype(jnp.float32)
@@ -163,31 +142,10 @@ def matmul(a, b, passes: int):
             + jnp.matmul(al, bh, precision=hp))
 
 
-def sage_loss(params, h0, blocks, labels, valid, passes):
-    """Masked mean cross-entropy of GraphSAGE over ``blocks`` (top level
-    first); ``h0`` is aligned with the bottom frontier."""
-    h = h0
-    depth = len(blocks)
-    for layer in range(depth):
-        self_pos, nb, mask = blocks[depth - 1 - layer]
-        p = params[layer]
-        m = mask[..., None].astype(jnp.float32)
-        agg = jnp.sum(h[nb] * m, axis=1) / jnp.maximum(jnp.sum(m, axis=1), 1)
-        h = (matmul(h[self_pos], p["w_self"], passes)
-             + matmul(agg, p["w_neigh"], passes) + p["b"])
-        if layer < depth - 1:
-            h = jax.nn.relu(h)
-    logp = jax.nn.log_softmax(h, axis=-1)
-    nll = -jnp.take_along_axis(logp, jnp.maximum(labels, 0)[:, None],
-                               axis=1)[:, 0]
-    ok = valid & (labels >= 0)
-    return jnp.sum(jnp.where(ok, nll, 0)) / jnp.maximum(jnp.sum(ok), 1)
-
-
-@functools.partial(jax.jit, static_argnames=("passes",))
-def _loss_and_grad(params, h0, blocks, labels, valid, passes):
-    return jax.value_and_grad(sage_loss)(params, h0, blocks, labels, valid,
-                                         passes)
+@functools.partial(jax.jit, static_argnames=("loss", "passes"))
+def _loss_and_grad(params, h0, blocks, labels, valid, *, loss, passes):
+    return jax.value_and_grad(loss)(params, h0, blocks, labels, valid,
+                                    passes)
 
 
 def clip_global(grads, max_norm: float):
@@ -219,10 +177,14 @@ def adamw(params, grads, state, opt: dict):
 
 
 def train_steps(graph, feats, labels, *, seed: int, base_salt: int,
-                batch: int, fanouts, window, dims, opt: dict,
-                steps: int = 3, passes: int = 6,
+                batch: int, fanouts, window, init_params, reference_loss,
+                opt: dict, steps: int = 3, passes: int = 6,
                 half_batch: bool = False, alter_bottom: bool = False):
     """Run ``steps`` reference training steps from ``seed``'s weights.
+
+    ``init_params(seed)`` gives the float32 weights and
+    ``reference_loss(params, h0, blocks, labels, valid, passes)`` the
+    model's loss (the configuration's model module).
 
     ``graph`` is ``(indptr, indices)`` on the host, ``feats`` the (n, D)
     float32 host features and ``labels`` the (n,) int32 labels, -1 where
@@ -237,7 +199,7 @@ def train_steps(graph, feats, labels, *, seed: int, base_salt: int,
     """
     indptr, indices = graph
     caps = block_sizes(batch, fanouts)
-    params0 = jax.tree.map(np.asarray, init_params(seed, dims))
+    params0 = jax.tree.map(np.asarray, init_params(seed))
     params = params0
     zeros = jax.tree.map(np.zeros_like, params)
     state = (0, zeros, zeros)
@@ -258,7 +220,7 @@ def train_steps(graph, feats, labels, *, seed: int, base_salt: int,
         loss, grads = _loss_and_grad(
             params, jnp.asarray(h0), blocks,
             jnp.asarray(labels[seeds].astype(np.int32)),
-            jnp.asarray(valid), passes=passes)
+            jnp.asarray(valid), loss=reference_loss, passes=passes)
         grads = jax.tree.map(lambda g: np.asarray(g, np.float32), grads)
         grads = clip_global(grads, float(opt["grad_clip"]))
         if k == 0:

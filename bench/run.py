@@ -3,12 +3,22 @@
     python3 bench/run.py --workload products.train.b1000 --seed 7 \\
         --seconds 10 --trace 0
 
-The cell, its configuration, its traffic mix and its per-layer metrics
-are found by name: ``BENCHMARK.json`` at the checkout's root names the
-cell's configuration (``file``) and traffic mix; the harness reads
-``bench/traffic/<traffic>.json``, ``bench/limits/<workload>.json`` and one
-reader ``bench/metrics/<metric>.py`` per per-layer metric.  Adding a cell
-adds files and entries; no file here changes.
+The cell, its configuration, its model, its traffic mix and its
+per-layer metrics are found by name: ``BENCHMARK.json`` at the checkout's
+root names the cell's configuration (``file``) and traffic mix; the
+harness reads ``bench/traffic/<traffic>.json``,
+``bench/limits/<workload>.json``, the model module
+``bench/models/<model>.py`` that the configuration's ``model`` names, and
+one reader ``bench/metrics/<metric>.py`` per per-layer metric.  Adding a
+cell or a model adds files and entries; no file here changes.
+
+A model module gives ``init_params(seed, config)`` (the float32 weights
+the program and the reference both start from), ``program_loss(config)``
+(the program's ``loss_fn(params, mfgs, h_src, labels, valid)``),
+``reference_loss(params, h0, blocks, labels, valid, passes)`` (plain
+``jax.numpy``, its matrix products through ``reference.matmul``) and
+``step_flops(batch, fanouts, config)`` (forward plus backward FLOPs at
+``flops.block_sizes``' nominal sizes).
 
 ``main`` first pins the host allocator's thresholds at glibc's static
 defaults (``steady_allocator``).  The program runs at the matrix-product precision
@@ -17,9 +27,10 @@ its configuration states (``matmul_precision``, set with ``jax.default_matmul_pr
 One run is one process.  Set-up (counted in ``setup_s``) loads the
 cached graph (generating it on a checkout's first run), draws the
 features, builds the program's ``Pipeline``, makes the weights on the
-device from ``--seed``, and drives the program's training driver through
-its first steps: three that the reference checks, then the traffic's
-warm-up steps, so that every program the window runs is compiled.  The
+device from ``--seed`` (the model module's ``init_params``), and drives
+the program's training driver through its first steps: three that the
+reference checks, then the traffic's warm-up steps, so that every
+program the window runs is compiled.  The
 window then calls ``driver.step`` and waits for each step's loss, for
 ``--seconds`` seconds.  With ``--trace 1`` the window is recorded by
 the JAX profiler and the per-layer metrics are read from the trace;
@@ -77,10 +88,12 @@ def say(*parts) -> None:
 
 # ------------------------------------------------------------------ cells
 
-def load_cell(name: str, root: Path = ROOT) -> types.SimpleNamespace:
+def load_cell(name: str, root: Path = ROOT,
+              models_dir: Path | None = None) -> types.SimpleNamespace:
     """Everything that belongs to cell ``name``, found by name under
-    ``root``: its ``BENCHMARK.json`` entry, configuration, traffic mix,
-    limits and metric definitions."""
+    ``root``: its ``BENCHMARK.json`` entry, configuration, model module
+    (from ``models_dir``, by default ``bench/models`` under ``root``),
+    traffic mix, limits and metric definitions."""
     bench = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -88,10 +101,12 @@ def load_cell(name: str, root: Path = ROOT) -> types.SimpleNamespace:
     cell = cells[name]
     entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     here = root / "bench"
+    config = json.loads((root / entry["file"]).read_text())
+    models_dir = Path(models_dir or here / "models")
     applies = lambda m: name in m.get("workloads", [name])
     return types.SimpleNamespace(
-        name=name, chips=int(cell["chips"]),
-        config=json.loads((root / entry["file"]).read_text()),
+        name=name, chips=int(cell["chips"]), config=config,
+        model=load_model(models_dir, config["model"]),
         traffic=json.loads(
             (here / "traffic" / f"{cell['traffic']}.json").read_text()),
         limits=json.loads((here / "limits" / f"{name}.json").read_text()),
@@ -100,14 +115,28 @@ def load_cell(name: str, root: Path = ROOT) -> types.SimpleNamespace:
         metrics_dir=here / "metrics")
 
 
-def load_reader(metrics_dir: Path, name: str):
-    """The ``read(ctx)`` function of ``<metrics_dir>/<name>.py``."""
-    path = Path(metrics_dir) / f"{name}.py"
+def _load(path: Path, prefix: str, name: str) -> types.ModuleType:
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        prefix + name.replace(".", "_").replace("-", "_"), path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def load_reader(metrics_dir: Path, name: str):
+    """The ``read(ctx)`` function of ``<metrics_dir>/<name>.py``."""
+    return _load(Path(metrics_dir) / f"{name}.py", "bench_metric_",
+                 name).read
+
+
+def load_model(models_dir: Path, name: str) -> types.ModuleType:
+    """The model module ``<models_dir>/<name>.py``; a model with no
+    module is an error that names the file."""
+    path = Path(models_dir) / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"model {name!r} has no module: no file {path}")
+    return _load(path, "bench_model_", name)
 
 
 def base_salt(seed: int) -> int:
@@ -115,12 +144,6 @@ def base_salt(seed: int) -> int:
     neighbouring seeds share no steps."""
     x = np.array([int(seed) % 2**64], np.uint64)
     return int(graphgen.mix64(x)[0] >> np.uint64(32))
-
-
-def dims_of(config: dict) -> list[int]:
-    layers = int(config["num_layers"])
-    return ([int(config["in_dim"])] + [int(config["hidden_dim"])]
-            * (layers - 1) + [int(config["num_classes"])])
 
 
 # ----------------------------------------------------------------- set-up
@@ -160,8 +183,7 @@ def build(cell, *, graph_cache=graphgen.CACHE_DIR):
     config, traffic = cell.config, cell.traffic
     fields = graphgen.graph_fields(config)
     t0 = time.perf_counter()
-    arrays, built = graphgen.load_or_build(config["name"], fields,
-                                           graph_cache)
+    arrays, built = graphgen.load_or_build(fields, graph_cache)
     say(f"graph: {'generated and cached' if built else 'loaded from cache'}"
         f" in {time.perf_counter() - t0:.1f} s, "
         f"{arrays['indptr'].shape[0] - 1:,} nodes, "
@@ -193,25 +215,16 @@ def build(cell, *, graph_cache=graphgen.CACHE_DIR):
 
 def start_driver(pipe, cell, seed: int):
     """Weights from ``seed`` on the device, AdamW state, and the
-    program's training driver, as ``launch/train_gnn.py`` builds them."""
-    from repro.models import gnn
+    program's training driver over the model module's loss, as
+    ``launch/train_gnn.py`` builds them."""
     from repro.optim import init_opt_state
 
     config = cell.config
-    gcfg = gnn.GNNConfig(in_dim=int(config["in_dim"]),
-                         hidden_dim=int(config["hidden_dim"]),
-                         num_classes=int(config["num_classes"]),
-                         num_layers=int(config["num_layers"]),
-                         fanouts=tuple(config["fanouts"]),
-                         dropout=float(config["dropout"]))
-
-    def loss_fn(p, mfgs, h_src, labels, valid):
-        return gnn.gnn_loss(p, mfgs, h_src, labels, valid, gcfg)
-
-    params = reference.init_params(seed, dims_of(config))
+    params = cell.model.init_params(seed, config)
     opt_state = init_opt_state(params, kind=config["optimizer"])
     driver = pipe.train_driver(
-        loss_fn, batch=int(cell.traffic["batch"]), lr=float(config["lr"]),
+        cell.model.program_loss(config), batch=int(cell.traffic["batch"]),
+        lr=float(config["lr"]),
         optimizer=config["optimizer"], grad_clip=float(config["grad_clip"]),
         base_salt=base_salt(seed), mode=cell.traffic["driver"])
     return driver, params, opt_state
@@ -239,8 +252,9 @@ def reference_of(cell, host, seed: int, **faults):
         host["graph"], host["feats"], host["labels"], seed=seed,
         base_salt=base_salt(seed), batch=int(cell.traffic["batch"]),
         fanouts=tuple(config["fanouts"]), window=config["sampler_window"],
-        dims=dims_of(config), opt=optimizer_of(config),
-        steps=CHECKED_STEPS, **faults)
+        init_params=lambda s: cell.model.init_params(s, config),
+        reference_loss=cell.model.reference_loss,
+        opt=optimizer_of(config), steps=CHECKED_STEPS, **faults)
 
 
 def optimizer_of(config: dict) -> dict:
@@ -385,10 +399,12 @@ def _run(cell, log, devices, *, seed, seconds, trace, t_start,
         path = next(Path(tmp).rglob("*.xplane.pb"))
         reduced = trace_reduce.reduce(path)
         shutil.rmtree(tmp, ignore_errors=True)
+        fanouts = tuple(cell.config["fanouts"])
         ctx = types.SimpleNamespace(
             trace=reduced, steps=steps, window_s=window_s, batch=batch,
-            fanouts=tuple(cell.config["fanouts"]),
-            dims=dims_of(cell.config), device_kind=devices[0].device_kind,
+            fanouts=fanouts, config=cell.config,
+            step_flops=cell.model.step_flops(batch, fanouts, cell.config),
+            device_kind=devices[0].device_kind,
             chips=len(devices), compile_setup_s=log.seconds["setup"],
             window_compiles=log.backend["window"],
             peak=peaks.lookup(devices[0].device_kind))

@@ -42,7 +42,7 @@ def main(argv=None) -> int:
         return reduce(path, *a, **k)
 
     trace_reduce.reduce = keep
-    cell = run.load_cell("toy.train", TOY)
+    cell = run.load_cell("toy.train", TOY, models_dir=BENCH / "models")
     cell.per_layer = run.load_cell("products.train.b1000").per_layer
     cell.metrics_dir = BENCH / "metrics"
     result = run.run_cell(cell, seed=args.seed, seconds=args.seconds,

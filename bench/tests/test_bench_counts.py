@@ -1,5 +1,5 @@
 """The benchmark's yardstick arithmetic: work counts, peaks, graph
-generation and its cache, and the cells found by name."""
+generation and its cache, and the cells and models found by name."""
 import json
 import os
 import shutil
@@ -18,7 +18,9 @@ import graphgen
 import peaks
 import run
 
-PRODUCTS_DIMS = (100, 256, 256, 47)
+PRODUCTS = json.loads((BENCH / "configs" / "graphsage-products.json")
+                      .read_text())
+graphsage = run.load_model(BENCH / "models", "graphsage")
 
 
 def test_block_sizes_products_b1000():
@@ -33,9 +35,41 @@ def test_sage_flops_products_b1000_by_hand():
     top = 2 * 2 * 1_000 * 256 * 47 + 1_000 * 15 * 256
     fwd = bottom + middle + top
     assert fwd == 22_397_632_000
-    assert flops.sage_forward_flops(1000, (15, 10, 5), PRODUCTS_DIMS) == fwd
-    assert flops.sage_step_flops(1000, (15, 10, 5), PRODUCTS_DIMS) \
+    assert graphsage.forward_flops(1000, (15, 10, 5), PRODUCTS) == fwd
+    assert graphsage.step_flops(1000, (15, 10, 5), PRODUCTS) \
         == 3 * fwd == 67_192_896_000
+
+
+def _parent_sage_step_flops(batch, fanouts, dims):
+    """The count ``mfu`` read before models were found by name."""
+    sizes = flops.block_sizes(batch, fanouts)
+    depth = len(fanouts)
+    total = 0
+    for layer in range(depth):
+        d = depth - 1 - layer
+        n_dst, f = sizes[d], int(fanouts[d])
+        d_in, d_out = int(dims[layer]), int(dims[layer + 1])
+        total += 2 * 2 * n_dst * d_in * d_out + n_dst * f * d_in
+    return 3 * total
+
+
+def _graphsage_cells() -> list[str]:
+    """The benchmark's cells whose configuration names ``graphsage``."""
+    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    model = {c["name"]: json.loads((BENCH.parent / c["file"]).read_text())
+             ["model"] for c in bench["configs"]}
+    return [w["name"] for w in bench["workloads"]
+            if model[w["config"]] == "graphsage"]
+
+
+@pytest.mark.parametrize("workload", _graphsage_cells())
+def test_graphsage_step_flops_is_the_parents_count(workload):
+    cell = run.load_cell(workload)
+    c, batch = cell.config, int(cell.traffic["batch"])
+    dims = ([c["in_dim"]] + [c["hidden_dim"]] * (c["num_layers"] - 1)
+            + [c["num_classes"]])
+    want = _parent_sage_step_flops(batch, c["fanouts"], dims)
+    assert cell.model.step_flops(batch, c["fanouts"], c) == want
 
 
 def test_fused_sample_bytes_products_b1000_by_hand():
@@ -77,17 +111,32 @@ def test_graph_is_deterministic_csc_without_self_loops():
 
 
 def test_graph_cache_second_build_loads(tmp_path, monkeypatch):
-    first, built = graphgen.load_or_build("toy", TOY, tmp_path)
+    first, built = graphgen.load_or_build(TOY, tmp_path)
     assert built
     monkeypatch.setattr(graphgen, "generate", lambda g: pytest.fail(
         "a cached graph was generated again"))
-    second, built = graphgen.load_or_build("toy", TOY, tmp_path)
+    second, built = graphgen.load_or_build(TOY, tmp_path)
     assert not built
     for k in first:
         np.testing.assert_array_equal(first[k], second[k])
     # another graph field is another key, so another directory
     assert graphgen.cache_key(TOY) != graphgen.cache_key(
         dict(TOY, avg_degree=13))
+    assert len(list(tmp_path.iterdir())) == 1
+
+
+def test_configs_on_one_graph_share_its_cache(tmp_path, monkeypatch):
+    """Two configurations of different names on the same graph fields
+    generate the graph once, and the second loads it."""
+    sage = dict(TOY, name="graphsage-toy", model="graphsage")
+    other = dict(TOY, name="gcn-toy", model="gcn_toy", hidden_dim=64)
+    calls = []
+    generate = graphgen.generate
+    monkeypatch.setattr(graphgen, "generate",
+                        lambda g: calls.append(g) or generate(g))
+    built = [graphgen.load_or_build(graphgen.graph_fields(c), tmp_path)[1]
+             for c in (sage, other)]
+    assert built == [True, False] and len(calls) == 1
     assert len(list(tmp_path.iterdir())) == 1
 
 
@@ -104,6 +153,8 @@ def _drop_in(root: Path):
     toy = BENCH / "tests" / "data" / "toy"
     shutil.copytree(toy, root, dirs_exist_ok=True)
     shutil.copytree(BENCH / "metrics", root / "bench" / "metrics")
+    shutil.copytree(BENCH / "models", root / "bench" / "models",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     (root / "bench" / "configs" / "another.json").write_text(json.dumps(
         dict(json.loads((toy / "bench/configs/graphsage-toy.json")
                         .read_text()), name="another", hidden_dim=64)))
@@ -156,6 +207,9 @@ def test_every_benchmark_cell_has_its_files():
         assert set(cell.limits) >= {"loss_gap", "grad_gap", "change_gap",
                                     "hub_gap"}
         assert [m["name"] for m in cell.end_to_end][-1] == "setup_s"
+        for fn in ("init_params", "program_loss", "reference_loss",
+                   "step_flops"):
+            assert callable(getattr(cell.model, fn)), (w["name"], fn)
         for m in cell.per_layer:
             assert callable(run.load_reader(cell.metrics_dir, m["name"]))
 

@@ -18,7 +18,7 @@ TOY = BENCH / "tests" / "data" / "toy"
 
 def _run(tmp_path, monkeypatch):
     monkeypatch.setattr(run, "use_compile_cache", lambda root: "off")
-    cell = run.load_cell("toy.train", TOY)
+    cell = run.load_cell("toy.train", TOY, models_dir=BENCH / "models")
     cell.metrics_dir = BENCH / "metrics"
     return run.run_cell(cell, seed=2**31 + 11, seconds=0.3, trace=False,
                         root=TOY, require_tpu=False, graph_cache=tmp_path)

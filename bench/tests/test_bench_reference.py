@@ -2,6 +2,7 @@
 the CPU, layer by layer: the seed draw, the fused sampler's blocks
 (hubs above its neighbour window included), the feature rows fetched for
 the bottom frontier, and the loss with its gradients."""
+import functools
 import json
 import sys
 from pathlib import Path
@@ -20,6 +21,7 @@ import reference
 import run
 
 TOY = BENCH / "tests" / "data" / "toy"
+MODELS = BENCH / "models"
 BATCH = 16
 
 
@@ -31,10 +33,10 @@ def toy(tmp_path_factory):
     from repro.core.graph import CSCGraph
     from repro.pipeline import Pipeline, PipelineSpec
 
-    cell = run.load_cell("toy.train", TOY)
+    cell = run.load_cell("toy.train", TOY, models_dir=MODELS)
     config = cell.config
     fields = graphgen.graph_fields(config)
-    arrays, _ = graphgen.load_or_build(config["name"], fields,
+    arrays, _ = graphgen.load_or_build(fields,
                                        tmp_path_factory.mktemp("graphs"))
     feats = graphgen.features(fields, arrays["classes"], config["in_dim"])
     labels = np.where(arrays["labeled"], arrays["classes"],
@@ -110,21 +112,14 @@ def test_fetched_feature_rows_match(toy):
 
 
 def test_loss_and_grads_match(toy):
-    from repro.models.gnn import GNNConfig, gnn_loss
-
     cell, arrays, feats, labels, _ = toy
     c = cell.config
     seeds, batch = _prepared(toy, 3)
-    dims = run.dims_of(c)
-    params = reference.init_params(11, dims)
-    gcfg = GNNConfig(in_dim=dims[0], hidden_dim=dims[1],
-                     num_classes=dims[-1], num_layers=len(dims) - 1,
-                     fanouts=tuple(c["fanouts"]), dropout=0.0)
+    params = cell.model.init_params(11, c)
     mfgs = jax.tree.map(jnp.asarray, list(batch.mfgs))
-    got_loss, got_grads = jax.value_and_grad(gnn_loss)(
+    got_loss, got_grads = jax.value_and_grad(cell.model.program_loss(c))(
         params, mfgs, jnp.asarray(batch.h_src),
-        jnp.asarray(batch.seed_labels), jnp.asarray(batch.seed_valid),
-        gcfg)
+        jnp.asarray(batch.seed_labels), jnp.asarray(batch.seed_valid))
     frontiers, draws, _ = reference.sample_blocks(
         arrays["indptr"], arrays["indices"], seeds, c["fanouts"], 3,
         c["sampler_window"])
@@ -134,7 +129,7 @@ def test_loss_and_grads_match(toy):
     h0[:frontiers[-1].size] = feats[frontiers[-1]]
     want_loss, want_grads = reference._loss_and_grad(
         params, jnp.asarray(h0), blocks, jnp.asarray(labels[seeds]),
-        jnp.ones(BATCH, bool), passes=6)
+        jnp.ones(BATCH, bool), loss=cell.model.reference_loss, passes=6)
     np.testing.assert_allclose(float(got_loss), float(want_loss),
                                rtol=1e-5)
     for g, w in zip(jax.tree.leaves(got_grads),
@@ -153,7 +148,8 @@ def test_three_pass_control_fails_at_toy_size(toy):
     kw = dict(seed=5, base_salt=run.base_salt(5), batch=BATCH,
               fanouts=tuple(cell.config["fanouts"]),
               window=cell.config["sampler_window"],
-              dims=run.dims_of(cell.config),
+              init_params=lambda s: cell.model.init_params(s, cell.config),
+              reference_loss=cell.model.reference_loss,
               opt=run.optimizer_of(cell.config))
     graph = (arrays["indptr"], arrays["indices"])
     want = reference.train_steps(graph, feats, labels, **kw)
@@ -170,7 +166,7 @@ def test_every_limit_lies_between_its_readings():
     below the smallest reading of the control or a planted fault."""
     bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     cells = [run.load_cell(w["name"]) for w in bench["workloads"]]
-    for cell in cells + [run.load_cell("toy.train", TOY)]:
+    for cell in cells + [run.load_cell("toy.train", TOY, models_dir=MODELS)]:
         for name, r in cell.limits["readings"].items():
             limit = cell.limits[name]
             if r["upper"] is None:
@@ -178,3 +174,107 @@ def test_every_limit_lies_between_its_readings():
             else:
                 assert r["lower"] < limit < r["upper"], (cell.name, name)
                 assert r["upper"] >= 3 * r["lower"], (cell.name, name)
+
+
+# The parent's GraphSAGE reference, kept as the oracle of the model module:
+# its weights, its loss and its training loop, as they were before the
+# model was found by the configuration's ``model`` key.
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _parent_init(key, dims):
+    params = []
+    for i, (d_in, d_out) in enumerate(zip(dims[:-1], dims[1:])):
+        k1, k2 = jax.random.split(jax.random.fold_in(key, i))
+        scale = (2.0 / d_in) ** 0.5
+        params.append({
+            "w_self": jax.random.normal(k1, (d_in, d_out), jnp.float32)
+            * scale,
+            "w_neigh": jax.random.normal(k2, (d_in, d_out), jnp.float32)
+            * scale,
+            "b": jnp.zeros((d_out,), jnp.float32)})
+    return params
+
+
+def _parent_sage_loss(params, h0, blocks, labels, valid, passes):
+    h = h0
+    depth = len(blocks)
+    for layer in range(depth):
+        self_pos, nb, mask = blocks[depth - 1 - layer]
+        p = params[layer]
+        m = mask[..., None].astype(jnp.float32)
+        agg = jnp.sum(h[nb] * m, axis=1) / jnp.maximum(jnp.sum(m, axis=1), 1)
+        h = (reference.matmul(h[self_pos], p["w_self"], passes)
+             + reference.matmul(agg, p["w_neigh"], passes) + p["b"])
+        if layer < depth - 1:
+            h = jax.nn.relu(h)
+    logp = jax.nn.log_softmax(h, axis=-1)
+    nll = -jnp.take_along_axis(logp, jnp.maximum(labels, 0)[:, None],
+                               axis=1)[:, 0]
+    ok = valid & (labels >= 0)
+    return jnp.sum(jnp.where(ok, nll, 0)) / jnp.maximum(jnp.sum(ok), 1)
+
+
+@functools.partial(jax.jit, static_argnames=("passes",))
+def _parent_loss_and_grad(params, h0, blocks, labels, valid, passes):
+    return jax.value_and_grad(_parent_sage_loss)(params, h0, blocks, labels,
+                                                 valid, passes)
+
+
+def _parent_train_steps(graph, feats, labels, *, seed, base_salt, batch,
+                        fanouts, window, dims, opt, steps=3, passes=6):
+    indptr, indices = graph
+    caps = flops.block_sizes(batch, fanouts)
+    params0 = jax.tree.map(np.asarray, _parent_init(
+        jax.random.key(int(seed)), tuple(int(d) for d in dims)))
+    params = params0
+    zeros = jax.tree.map(np.zeros_like, params)
+    state = (0, zeros, zeros)
+    losses, hubs, grad1 = [], [], None
+    for k in range(steps):
+        salt = reference.step_salt(base_salt, k)
+        seeds = reference.draw_seeds(labels >= 0, batch, salt)
+        frontiers, draws, h = reference.sample_blocks(
+            indptr, indices, seeds, fanouts, salt, window)
+        hubs.append(h)
+        blocks = reference.block_indices(frontiers, draws, caps)
+        bottom = frontiers[-1]
+        h0 = np.zeros((caps[-1], feats.shape[1]), np.float32)
+        h0[:bottom.shape[0]] = feats[bottom]
+        loss, grads = _parent_loss_and_grad(
+            params, jnp.asarray(h0), blocks,
+            jnp.asarray(labels[seeds].astype(np.int32)),
+            jnp.asarray(np.ones(batch, bool)), passes=passes)
+        grads = jax.tree.map(lambda g: np.asarray(g, np.float32), grads)
+        grads = reference.clip_global(grads, float(opt["grad_clip"]))
+        if k == 0:
+            grad1 = grads
+        params, state = reference.adamw(params, grads, state, opt)
+        losses.append(float(loss))
+    return {"losses": losses, "grad1": grad1, "params0": params0,
+            "params": params, "hubs": hubs}
+
+
+@pytest.mark.parametrize("passes", [6, 3], ids=["reference", "control"])
+def test_module_reference_is_the_parents_bit_for_bit(toy, passes):
+    """``reference.train_steps`` through the ``graphsage`` module gives the
+    toy cell's losses, first gradient and parameters bit for bit as the
+    parent's GraphSAGE functions did, reference and control alike."""
+    cell, arrays, feats, labels, _ = toy
+    c = cell.config
+    graph = (arrays["indptr"], arrays["indices"])
+    kw = dict(seed=2**31 + 7, base_salt=run.base_salt(2**31 + 7),
+              batch=int(cell.traffic["batch"]), fanouts=tuple(c["fanouts"]),
+              window=c["sampler_window"], opt=run.optimizer_of(c),
+              passes=passes)
+    got = run.reference_of(cell, {"graph": graph, "feats": feats,
+                                  "labels": labels}, kw["seed"],
+                           passes=passes)
+    dims = ([c["in_dim"]] + [c["hidden_dim"]] * (c["num_layers"] - 1)
+            + [c["num_classes"]])
+    want = _parent_train_steps(graph, feats, labels, dims=dims, **kw)
+    assert got["losses"] == want["losses"]
+    assert got["hubs"] == want["hubs"]
+    for key in ("params0", "grad1", "params"):
+        for g, w in zip(jax.tree.leaves(got[key]),
+                        jax.tree.leaves(want[key]), strict=True):
+            np.testing.assert_array_equal(g, w)
